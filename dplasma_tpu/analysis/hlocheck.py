@@ -173,16 +173,8 @@ class HloOp:
     shape: Tuple[int, ...]    # result dims (() for tuples/scalars)
     bytes: int                # result buffer bytes (tuple = sum)
     line: str                 # the full instruction line (attrs)
-
-    @property
-    def source(self) -> str:
-        m = re.search(r'source_file="([^"]*)"', self.line)
-        return m.group(1) if m else ""
-
-    @property
-    def source_line(self) -> int:
-        m = re.search(r"source_line=(\d+)", self.line)
-        return int(m.group(1)) if m else 0
+    source: str = ""          # innermost user frame's file, from the
+    source_line: int = 0      # module's stack-frame tables
 
 
 @dataclass
@@ -198,6 +190,8 @@ class HloModule:
     #: parameter count of the ENTRY computation (reduce regions etc.
     #: have their own parameters — those don't count)
     entry_params: int = 0
+    #: instruction name -> result element type (operands print bare)
+    dtypes: Dict[str, str] = field(default_factory=dict)
 
     def count(self, opcode: str) -> int:
         return sum(1 for o in self.ops if o.opcode == opcode)
@@ -208,7 +202,11 @@ class HloModule:
         for o in self.ops:
             kind = _HLO_COLLECTIVES.get(o.opcode)
             if kind:
-                c[kind] += 1
+                # the collective combiner merges independent exchanges
+                # into one op with several operands: count each
+                m = re.search(re.escape(o.opcode) + r"\(([^)]*)\)",
+                              o.line)
+                c[kind] += max(m.group(1).count("%") if m else 1, 1)
             elif o.opcode == "custom-call" and _RING_MARKER in o.line:
                 # a Mosaic-lowered explicit ICI-ring kernel: wire
                 # traffic exactly like the named collectives
@@ -329,13 +327,45 @@ def shape_bytes(type_str: str) -> Tuple[str, Tuple[int, ...], int]:
     return first[0], first[1], total
 
 
+def _stack_frames(text: str) -> Dict[int, Tuple[str, int]]:
+    """stack_frame_id -> (file, line) from the module's FileNames /
+    FileLocations / StackFrames tables (where op metadata points)."""
+    tables: Dict[str, Dict[int, str]] = {}
+    cur = None
+    for line in text.splitlines():
+        if line in ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames"):
+            cur = tables.setdefault(line, {})
+            continue
+        m = re.match(r"(\d+) (.*)$", line) if cur is not None else None
+        if m is None:
+            cur = None
+            continue
+        cur[int(m.group(1))] = m.group(2)
+    files = {k: v.strip('"') for k, v in
+             tables.get("FileNames", {}).items()}
+    locs = {}
+    for k, v in tables.get("FileLocations", {}).items():
+        f = re.search(r"file_name_id=(\d+)", v)
+        ln = re.search(r"\bline=(\d+)", v)
+        locs[k] = (files.get(int(f.group(1)), "") if f else "",
+                   int(ln.group(1)) if ln else 0)
+    frames = {}
+    for k, v in tables.get("StackFrames", {}).items():
+        loc = re.search(r"file_location_id=(\d+)", v)
+        frames[k] = locs.get(int(loc.group(1)), ("", 0)) if loc \
+            else ("", 0)
+    return frames
+
+
 def parse_module(text: str) -> HloModule:
     """Parse one compiled module's text (``compiled.as_text()``) into
     its structural view: header aliasing + every instruction's result
-    type and opcode. Parsing is line-based and forgiving — an HLO line
-    the grammar does not recognize is skipped, never fatal (the checks
-    only reason about ops that parsed)."""
+    type, opcode and source frame. Parsing is line-based and forgiving
+    — an HLO line the grammar does not recognize is skipped, never
+    fatal (the checks only reason about ops that parsed)."""
     mod = HloModule()
+    frames = _stack_frames(text)
     header, _, body = text.partition("\n")
     m = re.search(r"HloModule\s+([\w.\-]+)", header)
     if m:
@@ -351,25 +381,33 @@ def parse_module(text: str) -> HloModule:
             in_entry = True
         elif in_entry and line.rstrip() == "}":
             in_entry = False
-        om = _OP_RE.match(line)
+        # long tuple types carry /*index=N*/ position comments
+        om = _OP_RE.match(re.sub(r"/\*index=\d+\*/", "", line))
         if not om:
             continue
         name, type_str, opcode = om.groups()
         dtype, shape, nbytes = shape_bytes(type_str)
         if in_entry and opcode == "parameter":
             mod.entry_params += 1
+        sf = re.search(r"stack_frame_id=(\d+)", line)
+        source, source_line = frames.get(int(sf.group(1)), ("", 0)) \
+            if sf else ("", 0)
         mod.ops.append(HloOp(name=name, opcode=opcode, dtype=dtype,
-                             shape=shape, bytes=nbytes, line=line))
+                             shape=shape, bytes=nbytes, line=line,
+                             source=source, source_line=source_line))
+        mod.dtypes[name] = dtype
     return mod
 
 
-def _convert_types(op: HloOp) -> Optional[Tuple[str, str]]:
+def _convert_types(mod: HloModule, op: HloOp
+                   ) -> Optional[Tuple[str, str]]:
     """(src_dtype, dst_dtype) of a convert instruction, None when the
-    operand type cannot be read off the line."""
-    m = re.search(r"convert\(([a-zA-Z][a-zA-Z0-9]*)\[", op.line)
-    if m is None or not op.dtype:
+    operand's type is unknown."""
+    m = re.search(r"convert\(%([\w.\-]+)\)", op.line)
+    src = mod.dtypes.get(m.group(1)) if m else None
+    if not src or not op.dtype:
         return None
-    return m.group(1), op.dtype
+    return src, op.dtype
 
 
 # ---------------------------------------------------------------------
@@ -503,7 +541,7 @@ def check_precision(mod: HloModule, res: HloResult,
     for op in mod.ops:
         if op.opcode != "convert":
             continue
-        ct = _convert_types(op)
+        ct = _convert_types(mod, op)
         if ct is None:
             continue
         src, dst = ct

@@ -189,25 +189,12 @@ def capture(site: str, out: List[PallasContract]):
     """Within the context, ``pl.pallas_call`` records its contract into
     ``out`` and returns zeros of ``out_shape`` instead of running —
     kernels are never executed, so capture works even where the
-    kernel body itself could not lower (the point of a static gate).
-    Missing compiler-params API surface (older/newer jax spellings)
-    is shimmed for the duration so capture is version-independent."""
+    kernel body itself could not lower (the point of a static gate)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-    except Exception:  # tpu namespace absent: nothing to shim
-        pltpu = None
 
     orig_call = pl.pallas_call
-    shimmed = False
-    if pltpu is not None and not hasattr(pltpu, "CompilerParams"):
-        # jax<0.5 spells it TPUCompilerParams; the captured contract
-        # never reads it, so any kwargs-swallowing stand-in works
-        pltpu.CompilerParams = getattr(
-            pltpu, "TPUCompilerParams", lambda **kw: None)
-        shimmed = True
 
     def recorder(kernel, out_shape=None, **kw):
         grid = _norm_grid(kw.get("grid"))
@@ -243,8 +230,6 @@ def capture(site: str, out: List[PallasContract]):
         yield
     finally:
         pl.pallas_call = orig_call
-        if shimmed:
-            del pltpu.CompilerParams
 
 
 # ---------------------------------------------------------------------

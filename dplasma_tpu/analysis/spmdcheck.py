@@ -47,16 +47,17 @@ from collections import Counter
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
-#: jaxpr primitive name -> normalized collective kind (psum2 is what
-#: psum becomes under shard_map's replication-rule rewrite)
+#: jaxpr primitive name -> normalized collective kind (psum_invariant
+#: is what psum becomes under shard_map's varying-axes check)
 _COLLECTIVE_PRIMS = {
-    "psum": "psum", "psum2": "psum", "pmax": "pmax", "pmin": "pmin",
+    "psum": "psum", "psum_invariant": "psum", "pmax": "pmax",
+    "pmin": "pmin",
     "all_gather": "all_gather", "ppermute": "ppermute",
     "all_to_all": "all_to_all", "reduce_scatter": "reduce_scatter",
 }
 
-#: pbroadcast is shard_map's replication bookkeeping, not wire traffic
-_IGNORED_PRIMS = {"pbroadcast"}
+#: pvary is shard_map's varying-axes bookkeeping, not wire traffic
+_IGNORED_PRIMS = {"pvary"}
 
 #: pallas_call name prefix marking an explicit ICI-ring kernel
 #: (kernels.pallas_ring): ``dplasma_ring_{bcast|shift}_{axis}``. These
@@ -188,7 +189,7 @@ def _axes_of(params: dict) -> Tuple[str, ...]:
 
 def _sub_jaxprs(v):
     """Yield every (Closed)Jaxpr reachable from one eqn param value."""
-    import jax.core as jc
+    import jax.extend.core as jc
     vs = v if isinstance(v, (tuple, list)) else (v,)
     for x in vs:
         if isinstance(x, jc.ClosedJaxpr):

@@ -13,7 +13,7 @@ __all__ = ["Driver", "IParam", "parse_arguments", "run_driver", "DRIVERS",
            "main"]
 
 
-def main(argv=None, prog=None):
+def main(argv=None, prog=None, inspect=None):
     import sys
     args = list(sys.argv[1:] if argv is None else argv)
     name = prog
@@ -34,4 +34,4 @@ def main(argv=None, prog=None):
         sys.stderr.write(f"unknown driver {base}; algos: "
                          + " ".join(sorted(DRIVERS)) + "\n")
         return 2
-    return run_driver(base, DRIVERS[algo], args)
+    return run_driver(base, DRIVERS[algo], args, inspect)

@@ -33,8 +33,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from dplasma_tpu.kernels.pallas_compat import (HAVE_PALLAS, pl,
-                                               x64_scope)
+from jax.experimental import pallas as pl
 
 
 def _two_sum(a, b):
@@ -129,11 +128,10 @@ def recombine_base(levels, base, sa, sb, w: int,
     sa32 = jnp.broadcast_to(jnp.asarray(sa).astype(f32), (M, 1))
     sb32 = jnp.broadcast_to(jnp.asarray(sb).astype(f32), (1, N))
     if interpret is None:
-        from dplasma_tpu.kernels.pallas_compat import interpret_default
-        interpret = interpret_default()
+        interpret = jax.default_backend() != "tpu"
     # trace the kernel with x64 OFF: every operand is 32-bit, and x64
     # mode makes index-map constants i64, which Mosaic refuses to mix
     # with the i32 grid index ("failed to legalize func.return")
-    with x64_scope(False):
+    with jax.enable_x64(False):
         oh, ol = _recombine_call(lv, bh, bl, sa32, sb32, w, interpret)
     return oh.astype(jnp.float64) + ol.astype(jnp.float64)

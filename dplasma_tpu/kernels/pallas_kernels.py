@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dplasma_tpu.kernels import pallas_compat
-
 _ENABLED = False
 # Threshold below which pallas dispatch is not worth it (one MXU pass).
 _MIN_DIM = 256
@@ -45,7 +43,8 @@ def enabled() -> bool:
 
 
 def _interpret() -> bool:
-    return pallas_compat.interpret_default()
+    """Kernels interpret everywhere but on a real TPU backend."""
+    return jax.default_backend() != "tpu"
 
 
 def _block(dim: int, want: int, quantum: int) -> int:
@@ -97,15 +96,17 @@ def _pad_to(x, m, n):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("alpha", "beta", "bm", "bn", "bk", "precision"))
+    static_argnames=("alpha", "beta", "bm", "bn", "bk", "precision",
+                     "interpret"))
 def gemm(a, b, c=None, *, alpha=1.0, beta=1.0, bm=512, bn=512, bk=512,
-         precision=jax.lax.Precision.HIGHEST):
+         precision=jax.lax.Precision.HIGHEST, interpret=None):
     """C = alpha * A @ B + beta * C as one fused Pallas kernel.
 
     A:(M,K) B:(K,N) C:(M,N), real f32/bf16. Inputs are padded up to the
     block quantum; the pad region is zero so the (M, N) result is exact.
     ``c=None`` (or beta=0) selects a two-input variant that never reads
-    C — no HBM traffic for it.
+    C — no HBM traffic for it. ``interpret=None`` interprets off the
+    TPU backend.
     """
     M, K = a.shape
     K2, N = b.shape
@@ -143,8 +144,8 @@ def gemm(a, b, c=None, *, alpha=1.0, beta=1.0, bm=512, bn=512, bk=512,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((gm * bm, gn * bn), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=_interpret(),
-        compiler_params=pallas_compat.compiler_params(
+        interpret=_interpret() if interpret is None else interpret,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(*operands)
     return out[:M, :N]

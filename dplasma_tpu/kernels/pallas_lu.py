@@ -35,9 +35,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from dplasma_tpu.kernels.pallas_compat import (HAVE_PALLAS,
-                                               interpret_default, pl,
-                                               x64_scope)
+from jax.experimental import pallas as pl
 
 JB = 8  # column register-block width
 
@@ -130,11 +128,11 @@ def _panel_call(a, interpret: bool):
 
 
 def eligible(a) -> bool:
-    """Trace-time gate for the fused LU panel: pallas present + f32 +
+    """Trace-time gate for the fused LU panel: f32 +
     JB-aligned width + whole panel within the VMEM residency budget
     (the ONE home of the gate both ops.lu dispatch branches share)."""
     from dplasma_tpu.kernels import pallas_qr
-    if not HAVE_PALLAS or a.ndim != 2 or a.dtype != jnp.float32:
+    if a.ndim != 2 or a.dtype != jnp.float32:
         return False
     return pallas_qr.eligible_shape(a.shape[0], a.shape[1])
 
@@ -145,8 +143,8 @@ def lu_panel(a, interpret: bool | None = None):
     must fit VMEM (callers chunk at 8192 rows x 256 cols)."""
     a = jnp.asarray(a, jnp.float32)
     if interpret is None:
-        interpret = interpret_default()
-    with x64_scope(False):
+        interpret = jax.default_backend() != "tpu"
+    with jax.enable_x64(False):
         packed, ipiv = _panel_call(a, interpret)
     M = a.shape[0]
     perm = jnp.arange(M, dtype=jnp.int32)

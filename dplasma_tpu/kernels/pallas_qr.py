@@ -36,9 +36,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from dplasma_tpu.kernels.pallas_compat import (HAVE_PALLAS,
-                                               interpret_default, pl,
-                                               x64_scope)
+from jax.experimental import pallas as pl
 
 JB = 8  # column register-block width (= the f32 sublane quantum)
 
@@ -139,8 +137,8 @@ def geqrt_panel(a, interpret: bool | None = None):
     from dplasma_tpu.kernels import householder as hh
     a = jnp.asarray(a, jnp.float32)
     if interpret is None:
-        interpret = interpret_default()
-    with x64_scope(False):
+        interpret = jax.default_backend() != "tpu"
+    with jax.enable_x64(False):
         packed, taus = _geqrt_call(a, interpret)
     v, _ = hh.split_qr(packed)
     return packed, v, hh.larft(v, taus)
@@ -160,8 +158,8 @@ def eligible_shape(m: int, nb: int, itemsize: int = 4) -> bool:
 
 
 def eligible(a) -> bool:
-    """Trace-time gate for the fused panel: pallas present + f32 +
+    """Trace-time gate for the fused panel: f32 +
     the shape gate."""
-    if not HAVE_PALLAS or a.ndim != 2 or a.dtype != jnp.float32:
+    if a.ndim != 2 or a.dtype != jnp.float32:
         return False
     return eligible_shape(a.shape[0], a.shape[1])

@@ -95,15 +95,9 @@ def ring_runtime_ok() -> bool:
     """Can the ring kernels actually execute here? Mosaic lowering of
     the remote-DMA primitives only exists on a TPU backend (interpret
     mode executes single-axis uniform shifts only — the test surface,
-    not the production one), and the pallas tpu namespace must
-    import."""
-    try:
-        import jax
-        from jax.experimental.pallas import tpu as pltpu
-    except Exception:
-        return False
-    return (jax.default_backend() == "tpu"
-            and hasattr(pltpu, "make_async_remote_copy"))
+    not the production one)."""
+    import jax
+    return jax.default_backend() == "tpu"
 
 
 def ring_geometry_ok(mesh, axis: str) -> bool:
@@ -318,14 +312,17 @@ def ring_bcast(x, *, root: int, axis: str,
             def _drain():
                 rc(sl).wait_send()
 
-    return pl.pallas_call(
-        kern,
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        scratch_shapes=[pltpu.SemaphoreType.DMA] * 3,
-        interpret=interpret,
-        name=f"{RING_NAME_PREFIX}bcast_{axis}")(x)
+    # 32-bit trace, as the other kernels: x64 mode would make the
+    # rank arithmetic i64 (every payload dtype here is 32-bit or less)
+    with jax.enable_x64(False):
+        return pl.pallas_call(
+            kern,
+            out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.SemaphoreType.DMA] * 3,
+            interpret=interpret,
+            name=f"{RING_NAME_PREFIX}bcast_{axis}")(x)
 
 
 def ring_shift(x, *, axis: str, axes: Tuple[Tuple[str, int], ...],
@@ -354,14 +351,15 @@ def ring_shift(x, *, axis: str, axes: Tuple[Tuple[str, int], ...],
         rcopy.start()
         rcopy.wait()
 
-    return pl.pallas_call(
-        kern,
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        scratch_shapes=[pltpu.SemaphoreType.DMA] * 2,
-        interpret=interpret,
-        name=f"{RING_NAME_PREFIX}shift_{axis}")(x)
+    with jax.enable_x64(False):
+        return pl.pallas_call(
+            kern,
+            out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.SemaphoreType.DMA] * 2,
+            interpret=interpret,
+            name=f"{RING_NAME_PREFIX}shift_{axis}")(x)
 
 
 def ring_allreduce(x, *, axis: str,

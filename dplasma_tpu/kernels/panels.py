@@ -33,13 +33,12 @@ latency-bound dispatches.  This module replaces the chain:
 
 Selection rides MCA ``panel.kernel`` in {auto, chain, rec, tree,
 pallas}: ``chain`` is bit-identical to the pre-engine routes, ``auto``
-resolves per (route, backend) — the tree/rec kernels on MXU backends
-where the vendor panel calls are the measured bottleneck, ``chain`` on
-CPU where LAPACK panels already win.  ``pallas`` selects the fused
-Pallas panel kernels (kernels/pallas_lu, kernels/pallas_qr) where the
-runtime probe passes and the shape fits VMEM, falling back to rec/tree
-otherwise — so the XLA paths carry the win on hosts where the pallas
-runtime API is absent.
+resolves per (route, backend) — the tree QR panel on MXU backends,
+``chain`` everywhere else (``rec`` stays opt-in: see _TPU_DEFAULTS).
+``pallas`` selects the fused Pallas panel kernels (kernels/pallas_lu,
+kernels/pallas_qr) where the shape fits VMEM, falling back to rec/tree
+otherwise; they run in interpret mode only, having no Mosaic lowering
+yet (tests/test_chip_compile.py).
 """
 from __future__ import annotations
 
@@ -55,10 +54,13 @@ from dplasma_tpu.utils import config as _cfg
 
 _KERNELS = ("auto", "chain", "rec", "tree", "pallas")
 
-#: per-route defaults for ``panel.kernel auto`` on MXU backends (CPU
-#: resolves to ``chain``: LAPACK panel kernels already run at memory
-#: speed there, and tier-1 compiles stay on the vendor calls)
-_TPU_DEFAULTS = {"qr": "tree", "lu": "rec", "nopiv": "rec"}
+#: per-route defaults for ``panel.kernel auto`` on MXU backends; every
+#: other route resolves to ``chain`` (the vendor calls). The LU routes
+#: stay on ``chain``: ``rec`` unrolls every pivot column, and at
+#: N=8192 nb=1024 its sgetrf program lowered to ~1M StableHLO lines
+#: whose TPU compile passed 26 GB of host memory (the 2x2 cyclic LU:
+#: 1.8M lines), against ~650 lines for ``chain``
+_TPU_DEFAULTS = {"qr": "tree"}
 
 _cfg.mca_register(
     "panel.kernel", "auto",
@@ -68,8 +70,8 @@ _cfg.mca_register(
     "bit-identical), rec (blocked-recursive LU panel, vectorized "
     "pivot search), tree (TSQR/CAQR binary-reduction QR panel + "
     "TSQR-HR compact-WY reconstruction), pallas (fused Pallas panel "
-    "kernels, runtime-gated, falls back to rec/tree), auto (tree/rec "
-    "on MXU backends, chain on CPU).")
+    "kernels, interpret mode only; fall back to rec/tree past VMEM), "
+    "auto (tree for QR on MXU backends, chain otherwise).")
 _cfg.mca_register(
     "panel.tree_leaf", "2",
     "Leaf-block height of the TSQR tree panel, in multiples of the "
@@ -87,26 +89,11 @@ def panel_kernel_config() -> str:
     return (_cfg.mca_get("panel.kernel") or "auto").lower()
 
 
-def _pallas_ready(route: str) -> bool:
-    """Can the fused Pallas panel kernel for ``route`` actually run
-    here? (import + API surface; per-shape VMEM eligibility is checked
-    at the call site)."""
-    try:
-        if route in ("lu", "nopiv"):
-            from dplasma_tpu.kernels import pallas_lu
-            return pallas_lu.HAVE_PALLAS
-        from dplasma_tpu.kernels import pallas_qr
-        return pallas_qr.HAVE_PALLAS
-    except Exception:
-        return False
-
-
 def panel_kernel(route: str) -> str:
     """Resolve the active panel kernel for ``route`` in {qr, lu,
     nopiv}: explicit MCA value wins (cross-family names map to the
     route's own engine: tree->rec for LU, rec->tree for QR), ``auto``
-    resolves per backend, and ``pallas`` degrades to the XLA tree/rec
-    path when the runtime probe fails."""
+    resolves per backend, and nopiv has no ``pallas`` kernel."""
     v = panel_kernel_config()
     if v not in _KERNELS:
         v = "auto"
@@ -115,9 +102,8 @@ def panel_kernel(route: str) -> str:
             v = _TPU_DEFAULTS.get(route, "chain")
         else:
             v = "chain"
-    if v == "pallas" and (route == "nopiv" or not _pallas_ready(route)):
-        v = "tree" if route == "qr" else "rec"  # nopiv has no fused
-        #                                          pallas kernel
+    if v == "pallas" and route == "nopiv":
+        v = "rec"  # nopiv has no fused pallas kernel
     if route == "qr" and v == "rec":
         v = "tree"
     elif route in ("lu", "nopiv") and v == "tree":

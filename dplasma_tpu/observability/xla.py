@@ -39,8 +39,6 @@ def _cost_dict(compiled) -> Optional[dict]:
         return {"error": repr(exc)}
     if ca is None:
         return None
-    if isinstance(ca, (list, tuple)):     # older jax: one dict per device
-        ca = ca[0] if ca else None
     return dict(ca) if isinstance(ca, dict) else None
 
 
@@ -81,12 +79,24 @@ def capture_compiled(compiled) -> dict:
                 mem[attr] = int(v)
         if mem:
             out["memory"] = mem
-            # peak live bytes: XLA reports it directly on some
-            # backends; otherwise args+outputs+temps bounds the
-            # footprint of one execution
-            out["peak_bytes"] = mem.get(
-                "peak_memory_in_bytes",
-                sum(mem.get(a, 0) for a in
-                    ("argument_size_in_bytes", "output_size_in_bytes",
-                     "temp_size_in_bytes")))
+            # peak live bytes as XLA reports it; the CPU's figure
+            # leaves the temps out, so there args+outputs+temps bounds
+            # the footprint of one execution
+            if "peak_memory_in_bytes" in mem and not _on_cpu(compiled):
+                out["peak_bytes"] = mem["peak_memory_in_bytes"]
+            else:
+                out["peak_bytes"] = sum(mem.get(a, 0) for a in (
+                    "argument_size_in_bytes", "output_size_in_bytes",
+                    "temp_size_in_bytes"))
     return out
+
+
+def _on_cpu(compiled) -> bool:
+    """Does ``compiled`` run on the host CPU (judged by the devices of
+    its shardings; the default backend when it has none)?"""
+    import jax
+    shardings = jax.tree.leaves((getattr(compiled, "input_shardings", ()),
+                                 getattr(compiled, "output_shardings", ())))
+    for s in shardings:
+        return next(iter(s.device_set)).platform == "cpu"
+    return jax.default_backend() == "cpu"

@@ -7,6 +7,9 @@ eps·N (ref tests/testing_zpotrf.c:86-121). No golden files, ever.
 """
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
 
 from dplasma_tpu.descriptors import TileMatrix
@@ -14,6 +17,11 @@ from dplasma_tpu.kernels import blas
 from dplasma_tpu.ops import norms
 
 THRESHOLD = 60.0
+
+# Each residual is one jitted program: op by op, every limb op of a
+# dd-precision product compiles on its own (hundreds of compiles on a
+# TPU). A fresh jit per call, so MCA and grid settings read at trace
+# time are never stale.
 
 
 def _eps(dtype):
@@ -33,6 +41,11 @@ def _tiny(dtype):
 
 def check_potrf(A0: TileMatrix, LL: TileMatrix, uplo: str = "L"):
     """||A - L L^H|| / (N ||A|| eps) — check_zpotrf semantics."""
+    r = jax.jit(functools.partial(_potrf_residual, uplo=uplo))(A0, LL)
+    return float(r), bool(r < THRESHOLD)
+
+
+def _potrf_residual(A0: TileMatrix, LL: TileMatrix, uplo: str):
     N = A0.desc.N
     a = norms._sym_full(A0, uplo, conj=True)
     x = LL.to_dense()
@@ -46,14 +59,19 @@ def check_potrf(A0: TileMatrix, LL: TileMatrix, uplo: str = "L"):
     anorm = jnp.max(jnp.abs(a))
     # zero-norm A0 (e.g. an all-zero generator) must give a finite
     # residual, not 0/0 = NaN
-    r = res / jnp.maximum(anorm * _eps(A0.dtype) * N, _tiny(A0.dtype))
-    return float(r), bool(r < THRESHOLD)
+    return res / jnp.maximum(anorm * _eps(A0.dtype) * N, _tiny(A0.dtype))
 
 
 def check_axmb(A0: TileMatrix, b: TileMatrix, x: TileMatrix,
                uplo: str | None = None):
     """||b - A x||_inf / (||A|| ||x|| N eps) — check_zaxmb semantics.
     ``uplo`` set means A0 stores a Hermitian triangle."""
+    val = jax.jit(functools.partial(_axmb_residual, uplo=uplo))(A0, b, x)
+    return float(val), bool(val < THRESHOLD)
+
+
+def _axmb_residual(A0: TileMatrix, b: TileMatrix, x: TileMatrix,
+                   uplo: str | None):
     N = A0.desc.N
     if uplo:
         a = norms._sym_full(A0, uplo, conj=True)
@@ -64,8 +82,7 @@ def check_axmb(A0: TileMatrix, b: TileMatrix, x: TileMatrix,
     r = bd - blas.dot(a, xd)
     num = jnp.max(jnp.abs(r))
     den = (jnp.max(jnp.abs(a)) * jnp.max(jnp.abs(xd)) * _eps(A0.dtype) * N)
-    val = num / jnp.maximum(den, _tiny(A0.dtype))
-    return float(val), bool(val < THRESHOLD)
+    return num / jnp.maximum(den, _tiny(A0.dtype))
 
 
 def check_solve(A0: TileMatrix, b: TileMatrix, x: TileMatrix,

@@ -39,10 +39,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax spells it jax.experimental.shard_map
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from dplasma_tpu.descriptors import TileMatrix
 from dplasma_tpu.kernels import blas as k

@@ -73,20 +73,29 @@ def _mask_mn(desc: TileDesc, x):
     return jnp.where((r < desc.M) & (c < desc.N), x, jnp.zeros((), x.dtype))
 
 
+def _build(desc: TileDesc, values) -> TileMatrix:
+    """``values(r, c)`` over the padded grid, masked to M x N, as one
+    program: op by op, each of the hash's ~20 elementwise ops compiles
+    and materializes on its own."""
+    def make():
+        r, c = _grid(desc)
+        return _mask_mn(desc, values(r, c))
+    return TileMatrix(jax.jit(make)(), desc)
+
+
 def plrnt(M: int, N: int, mb: int, nb: int, seed: int = 3872,
           dtype=jnp.float32, diagdom: bool = False,
           dist: Dist = Dist()) -> TileMatrix:
     """Random matrix (dplasma_zplrnt). ``diagdom`` adds max(M,N) to the
     diagonal (the reference's diagonal-dominant mode used before
     no-pivoting LU)."""
-    desc = TileDesc(M, N, mb, nb, dist)
-    r, c = _grid(desc)
-    v = _value(seed, r, c, dtype)
-    if diagdom:
-        bump = jnp.asarray(max(M, N), dtype=v.dtype)
-        v = jnp.where(r == c, v + bump, v)
-    data = _mask_mn(desc, v)
-    return TileMatrix(data, desc)
+    def values(r, c):
+        v = _value(seed, r, c, dtype)
+        if diagdom:
+            bump = jnp.asarray(max(M, N), dtype=v.dtype)
+            v = jnp.where(r == c, v + bump, v)
+        return v
+    return _build(TileDesc(M, N, mb, nb, dist), values)
 
 
 def plghe(bump: float, N: int, nb: int, seed: int = 3872,
@@ -95,19 +104,16 @@ def plghe(bump: float, N: int, nb: int, seed: int = 3872,
     """Hermitian matrix with real diagonal + ``bump`` (dplasma_zplghe).
     ``bump >= N`` yields a positive-definite matrix (the SPD generator
     under every Cholesky test, ref tests/testing_zpotrf.c:50)."""
-    mb = mb or nb
-    desc = TileDesc(N, N, mb, nb, dist)
-    r, c = _grid(desc)
-    lo = jnp.maximum(r, c)
-    hi = jnp.minimum(r, c)
-    v = _value(seed, lo, hi, dtype)  # canonical (unordered) index pair
-    if jnp.issubdtype(jnp.dtype(dtype), jnp.complexfloating):
-        v = jnp.where(r < c, v.conj(), v)  # upper = conj(lower)
-        v = jnp.where(r == c, v.real.astype(v.dtype), v)
-    bump_a = jnp.asarray(bump, dtype=v.dtype)
-    v = jnp.where(r == c, v + bump_a, v)
-    data = _mask_mn(desc, v)
-    return TileMatrix(data, desc)
+    def values(r, c):
+        lo = jnp.maximum(r, c)
+        hi = jnp.minimum(r, c)
+        v = _value(seed, lo, hi, dtype)  # canonical (unordered) index pair
+        if jnp.issubdtype(jnp.dtype(dtype), jnp.complexfloating):
+            v = jnp.where(r < c, v.conj(), v)  # upper = conj(lower)
+            v = jnp.where(r == c, v.real.astype(v.dtype), v)
+        bump_a = jnp.asarray(bump, dtype=v.dtype)
+        return jnp.where(r == c, v + bump_a, v)
+    return _build(TileDesc(N, N, mb or nb, nb, dist), values)
 
 
 def plgsy(bump: float, N: int, nb: int, seed: int = 3872,
@@ -115,13 +121,10 @@ def plgsy(bump: float, N: int, nb: int, seed: int = 3872,
           dist: Dist = Dist()) -> TileMatrix:
     """Complex-symmetric (not Hermitian) matrix + diagonal bump
     (dplasma_zplgsy)."""
-    mb = mb or nb
-    desc = TileDesc(N, N, mb, nb, dist)
-    r, c = _grid(desc)
-    lo = jnp.maximum(r, c)
-    hi = jnp.minimum(r, c)
-    v = _value(seed, lo, hi, dtype)
-    bump_a = jnp.asarray(bump, dtype=v.dtype)
-    v = jnp.where(r == c, v + bump_a, v)
-    data = _mask_mn(desc, v)
-    return TileMatrix(data, desc)
+    def values(r, c):
+        lo = jnp.maximum(r, c)
+        hi = jnp.minimum(r, c)
+        v = _value(seed, lo, hi, dtype)
+        bump_a = jnp.asarray(bump, dtype=v.dtype)
+        return jnp.where(r == c, v + bump_a, v)
+    return _build(TileDesc(N, N, mb or nb, nb, dist), values)

@@ -284,11 +284,9 @@ def _factor_refine_chol(af, L32, f64t):
     Li = lax.linalg.triangular_solve(
         jnp.tril(L32), jnp.eye(n, dtype=f32), left_side=True,
         lower=True)
-    M = jnp.matmul(jnp.matmul(Li, E.astype(f32),
-                              preferred_element_type=f32),
-                   Li.T, preferred_element_type=f32)
+    M = _dd.mm_f32(_dd.mm_f32(Li, E.astype(f32)), Li.T)
     phi = jnp.tril(M, -1) + 0.5 * jnp.diag(jnp.diag(M))
-    corr = jnp.matmul(jnp.tril(L32), phi, preferred_element_type=f32)
+    corr = _dd.mm_f32(jnp.tril(L32), phi)
     return jnp.tril(L + corr.astype(f64t))
 
 
@@ -306,11 +304,9 @@ def _factor_refine_r(ad, R32, f64t):
     Ri = lax.linalg.triangular_solve(
         jnp.triu(R32), jnp.eye(n, dtype=f32), left_side=True,
         lower=False)
-    M = jnp.matmul(jnp.matmul(Ri.T, E.astype(f32),
-                              preferred_element_type=f32),
-                   Ri, preferred_element_type=f32)
+    M = _dd.mm_f32(_dd.mm_f32(Ri.T, E.astype(f32)), Ri)
     phi = jnp.triu(M, 1) + 0.5 * jnp.diag(jnp.diag(M))
-    corr = jnp.matmul(phi, jnp.triu(R32), preferred_element_type=f32)
+    corr = _dd.mm_f32(phi, jnp.triu(R32))
     return jnp.triu(R + corr.astype(f64t))
 
 

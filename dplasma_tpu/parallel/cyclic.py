@@ -33,10 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from dplasma_tpu.descriptors import Dist, TileMatrix
 from dplasma_tpu.parallel import layout
@@ -476,10 +473,6 @@ def _potrf_cyclic_jit(data, desc: CyclicDesc, mesh, lookahead: int = 0,
             A = A - kb.dot(Lbelow, ct(W))
         return A.reshape(1, 1, mloc, nloc)
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
     f = shard_map(
         body, mesh=mesh,
         in_specs=PartitionSpec(pmesh.ROW_AXIS, pmesh.COL_AXIS, None,
@@ -489,7 +482,7 @@ def _potrf_cyclic_jit(data, desc: CyclicDesc, mesh, lookahead: int = 0,
         # pallas_call has no replication rule: the ring path must opt
         # out of shard_map's rep check (the off path keeps it — its
         # traced program is bit-identical to the pre-ring kernels)
-        **({"check_rep": False} if ring else {}))
+        **({"check_vma": False} if ring else {}))
     return f(data)
 
 
@@ -650,7 +643,7 @@ def _getrf_cyclic_jit(data, desc: CyclicDesc, mesh,
                    PartitionSpec(pmesh.ROW_AXIS, pmesh.COL_AXIS, None,
                                  None),
                    PartitionSpec(pmesh.ROW_AXIS, pmesh.COL_AXIS, None)),
-        **({"check_rep": False} if ring else {}))
+        **({"check_vma": False} if ring else {}))
     return f(data)
 
 
@@ -860,7 +853,7 @@ def _geqrf_cyclic_jit(data, desc: CyclicDesc, mesh,
                                  None),
                    PartitionSpec(pmesh.ROW_AXIS, pmesh.COL_AXIS, None,
                                  None, None)),
-        **({"check_rep": False} if ring else {}))
+        **({"check_vma": False} if ring else {}))
     return f(data)
 
 
@@ -1337,7 +1330,7 @@ def _panel_bcast_probe_jit(data, desc: CyclicDesc, mesh,
                                None),
         out_specs=PartitionSpec(pmesh.ROW_AXIS, pmesh.COL_AXIS, None,
                                 None),
-        **({"check_rep": False} if ring else {}))
+        **({"check_vma": False} if ring else {}))
     return f(data)
 
 

@@ -53,12 +53,19 @@ def active() -> Optional[Mesh]:
 @contextlib.contextmanager
 def use_grid(mesh: Optional[Mesh]):
     """Activate a mesh for the dynamic extent (analog of establishing the
-    process grid at ``parsec_init``)."""
+    process grid at ``parsec_init``). On a multi-device grid the dd
+    engine's Pallas recombine is off (MCA ``dd_epilogue=off``): GSPMD
+    cannot partition a Mosaic kernel, so the grid takes the XLA
+    recombine."""
+    from dplasma_tpu.utils import config as _cfg
     global _ACTIVE
     prev = _ACTIVE
     _ACTIVE = mesh
+    kv = {"dd_epilogue": "off"} if mesh is not None and mesh.size > 1 \
+        else {}
     try:
-        yield mesh
+        with _cfg.override_scope(kv, label="use_grid"):
+            yield mesh
     finally:
         _ACTIVE = prev
 

@@ -200,16 +200,6 @@ def armed() -> bool:
     return _S.plan is not None and _S.suppress == 0
 
 
-def rearm() -> None:
-    """Reset the armed plan's site counters and fault log without
-    disarming. For an ABANDONED trace (e.g. the accelerator lowering
-    failed and the whole program re-traces on the host backend): faults
-    recorded into the dead trace must not consume the budget or be
-    reported as executed. No-op when nothing is armed."""
-    if _S.plan is not None:
-        arm(_S.plan)
-
-
 def faults() -> List[dict]:
     return list(_S.faults)
 

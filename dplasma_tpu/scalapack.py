@@ -657,25 +657,18 @@ def _multirank(name: str, args):
 def dispatch(name: str, args) -> int:
     """Entry point called from the native shim. Returns INFO."""
     call_counts[name] = call_counts.get(name, 0) + 1
-    # d-precision ABI requires real f64 end-to-end (the reference links
-    # double BLAS); enable x64 before the first trace. f64 runs on the
-    # host CPU backend — TPU lacks f64 factorization expanders.
-    import contextlib
+    # d-precision ABI requires f64 end-to-end (the reference links
+    # double BLAS); enable x64 before the first trace. On the TPU the
+    # f64 work rides the dd limb engine, like the drivers' d path.
     import jax
-    prec = _prec_of(args)
-    ctx = contextlib.nullcontext()
-    if prec == "d":
+    if _prec_of(args) == "d":
         # only the d-precision ABI needs x64; don't disturb f32 hosts
         jax.config.update("jax_enable_x64", True)
-        cpus = jax.devices("cpu")
-        if cpus:
-            ctx = jax.default_device(cpus[0])
     try:
-        with ctx:
-            mr = _multirank(name, args)
-            if mr is not None:
-                return mr
-            return int(_HANDLERS[name](*args))
+        mr = _multirank(name, args)
+        if mr is not None:
+            return mr
+        return int(_HANDLERS[name](*args))
     except Exception as exc:  # surface as INFO<0, like xerbla
         import traceback
         traceback.print_exc()

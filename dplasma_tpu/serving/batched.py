@@ -211,7 +211,7 @@ def backward_errors(A, B, X):
     (A pads identity, b and x pad zero), so numerator and verdict are
     padding-invariant."""
     import jax.numpy as jnp
-    r = B - jnp.matmul(A, X)
+    r = B - jnp.matmul(A, X, precision=jax.lax.Precision.HIGHEST)
     num = jnp.max(jnp.abs(r), axis=(-2, -1))
     den = (jnp.maximum(jnp.max(jnp.abs(A), axis=(-2, -1)),
                        jnp.asarray(1.0, A.dtype))

@@ -45,7 +45,7 @@ import dataclasses
 import sys
 import threading
 import time
-from typing import Callable, NamedTuple, Optional, Tuple
+from typing import Callable, List, NamedTuple, Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -347,6 +347,12 @@ class ExecutableCache:
                         "cache_invalidate", op=key.op, n=key.n,
                         batch=key.batch)
             return gone
+
+    def entries(self) -> List[Entry]:
+        """A snapshot of the cached entries, least recently used
+        first."""
+        with self._lock:
+            return list(self._d.values())
 
     def stats(self) -> dict:
         """The cache economics summary for the run-report ``"serving"``

@@ -159,6 +159,9 @@ class SolveFuture:
         self._service = service
         self._group = group
         self._event = threading.Event()
+        # makes "first to settle" atomic: a racing resolve/fail pair
+        # must count once on the conservation ledger
+        self._settle_lock = threading.Lock()
         self._value = None
         self._error: Optional[BaseException] = None
         self.request_id: int = 0
@@ -168,10 +171,11 @@ class SolveFuture:
         return self._event.is_set()
 
     def _resolve(self, value, meta: dict) -> None:
-        first = not self._event.is_set()
-        self._value = value
-        self.meta.update(meta)
-        self._event.set()
+        with self._settle_lock:
+            first = not self._event.is_set()
+            self._value = value
+            self.meta.update(meta)
+            self._event.set()
         if first:
             # the conservation ledger: every admitted request resolves
             # exactly once (value or error) — the soak audit's
@@ -180,9 +184,10 @@ class SolveFuture:
                 "serving_resolved_total").inc()
 
     def _fail(self, exc: BaseException) -> None:
-        first = not self._event.is_set()
-        self._error = exc
-        self._event.set()
+        with self._settle_lock:
+            first = not self._event.is_set()
+            self._error = exc
+            self._event.set()
         if first:
             self._service.metrics.counter(
                 "serving_resolved_total").inc()

@@ -350,14 +350,10 @@ def test_getrf_dd_eager_many_panels():
         cfg.mca_set("dd_gemm", None)
 
 
-@pytest.mark.requires_pallas_interpret
 def test_pallas_recombine_base_matches_exact():
     """The Pallas double-single epilogue (interpret mode here) must
     match the exact emulated recombine to ~2^-45 relative — the DS
-    width contract (kernels/pallas_dd.py). Skipped via the conftest
-    ``requires_pallas_interpret`` probe: the kernel needs only a
-    working interpret-mode pallas_call (the tpu-namespace spelling
-    differences are absorbed by kernels.pallas_compat)."""
+    width contract (kernels/pallas_dd.py)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -412,3 +408,17 @@ def test_trsm_f64_extreme_magnitudes(rng):
     rel = np.abs(X - ref) / np.abs(ref).max(axis=0, keepdims=True)
     assert np.isfinite(X).all()
     assert rel.max() < 1e-10, rel.max()
+
+
+def test_pallas_epilogue_off_on_a_grid(monkeypatch, devices8):
+    """GSPMD cannot partition a Mosaic kernel: on a multi-device grid
+    the recombine takes the XLA path even on a float-float backend."""
+    import jax.numpy as jnp
+
+    from dplasma_tpu.kernels import dd
+    from dplasma_tpu.parallel import mesh as pmesh
+    monkeypatch.setattr(dd, "_ff_backend", lambda: True)
+    levels = [jnp.zeros((8, 128), jnp.int32)]
+    assert dd._pallas_epilogue_ok(levels, 128)
+    with pmesh.use_grid(pmesh.make_mesh(2, 2, devices8[:4])):
+        assert not dd._pallas_epilogue_ok(levels, 128)

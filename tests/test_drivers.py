@@ -177,3 +177,74 @@ def test_driver_dot_uses_global_recorder(tmp_path, capsys):
         # updates -> 9 tasks), not an accumulation
         assert not profiling.recorder.enabled
         assert len(profiling.recorder.tasks) == 9
+
+
+# ------------------------------------------- chip bring-up contracts
+
+def test_compile_error_propagates_without_host_fallback():
+    """A compile the default backend refuses is an error, never a
+    quiet re-run on another device (here: a Pallas kernel forced out
+    of interpret mode on the CPU)."""
+    import jax.numpy as jnp
+
+    from dplasma_tpu.drivers.common import run_driver
+    from dplasma_tpu.kernels import pallas_kernels as pk
+    x = jnp.ones((128, 128), jnp.float32)
+
+    def body(drv):
+        drv.progress(lambda a: pk.matmul(a, a, interpret=False), (x,),
+                     1.0)
+        return 0
+
+    with pytest.raises(ValueError, match="interpret mode"):
+        run_driver("testing_sgemm", body, ["-N", "128"])
+
+
+def test_inspect_sees_the_timed_op_before_close():
+    """``main(inspect=)``: the callback gets the driver with its -x
+    checks, the op's timings and the timed op's operands/result."""
+    seen = {}
+
+    def inspect(drv):
+        seen.update(checks=list(drv.report.checks),
+                    timings=drv.report.ops[-1]["timings"],
+                    out=drv.output, inputs=drv.inputs)
+
+    assert main(["-N", "64", "-t", "32", "-x"], prog="testing_spotrf",
+                inspect=inspect) == 0
+    assert [c["ok"] for c in seen["checks"]] == [True, True]
+    assert seen["timings"]["enq_s"] > 0
+    assert seen["out"].data.shape == seen["inputs"][0].data.shape
+
+
+def test_compile_cache_defaults_to_repo_dir(monkeypatch):
+    import os
+
+    import jax
+
+    from dplasma_tpu.utils import config
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    path = config.use_compile_cache()
+    assert path == config.REPO_CACHE_DIR
+    assert jax.config.jax_compilation_cache_dir == path
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert path == os.path.join(root, ".jax_cache")
+
+
+def test_driver_writes_cache_where_the_environment_says(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, a driver process caches its
+    executables there."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cache = tmp_path / "cache"
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(cache),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    r = subprocess.run(
+        [sys.executable, "-m", "dplasma_tpu.drivers", "testing_spotrf",
+         "-N", "64", "-t", "32"], cwd=root, env=env,
+        capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr
+    assert any(cache.iterdir())

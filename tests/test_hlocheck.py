@@ -216,7 +216,7 @@ def test_mutation_surplus_collective_named(devices8):
     (d,) = [d for d in res.diagnostics
             if d.kind == "surplus-collective"]
     assert "all-gather" in d.message and "GSPMD inserted" in d.message
-    assert d.op.startswith("all-gather")
+    assert d.op.startswith("all_gather")
     assert d.detail["compiled"] == d.detail["traced"] + 1
 
 
@@ -285,6 +285,15 @@ def test_mutation_demoting_convert_named():
     assert diags[0].op.startswith("convert")
 
 
+def _frames(path: str, line: int) -> str:
+    """The stack-frame tables a compiled module's metadata points at:
+    one frame at ``path:line``."""
+    return (f'\nFileNames\n1 "{path}"\n\nFunctionNames\n1 "f"\n\n'
+            f'FileLocations\n1 {{file_name_id=1 function_name_id=1 '
+            f'line={line} end_line={line} column=1 end_column=2}}\n\n'
+            f'StackFrames\n1 {{file_location_id=1 parent_frame_id=1}}\n')
+
+
 def test_demotion_allowed_at_registered_site():
     """The same demoting convert with a registered dd/limb
     source_file is the AUTHORIZED precision ladder — no diagnostic."""
@@ -293,11 +302,10 @@ def test_demotion_allowed_at_registered_site():
         '{(f32[4,4]{1,0})->bf16[4,4]{1,0}}\n\n'
         'ENTRY %main (p0: f32[4,4]) -> bf16[4,4] {\n'
         '  %p0 = f32[4,4]{1,0} parameter(0)\n'
-        '  %convert.1 = bf16[4,4]{1,0} convert(f32[4,4]{1,0} %p0), '
-        'metadata={op_name="x" source_file='
-        '"/repo/dplasma_tpu/kernels/dd.py" source_line=42}\n'
-        '  ROOT %r = bf16[4,4]{1,0} copy(bf16[4,4]{1,0} %convert.1)\n'
-        '}\n')
+        '  %convert.1 = bf16[4,4]{1,0} convert(%p0), '
+        'metadata={op_name="x" stack_frame_id=1}\n'
+        '  ROOT %r = bf16[4,4]{1,0} copy(%convert.1)\n'
+        '}\n' + _frames("/repo/dplasma_tpu/kernels/dd.py", 42))
     mod = hc.parse_module(text)
     res = hc.HloResult(kernel="dd-site")
     hc.check_precision(mod, res, working_bits=32)
@@ -322,11 +330,10 @@ def test_declared_demotion_quantizer_site_both_directions():
         '{(f32[4,4]{1,0})->s8[4,4]{1,0}}\n\n'
         'ENTRY %main (p0: f32[4,4]) -> s8[4,4] {\n'
         '  %p0 = f32[4,4]{1,0} parameter(0)\n'
-        '  %convert.1 = s8[4,4]{1,0} convert(f32[4,4]{1,0} %p0), '
-        'metadata={op_name="q" source_file='
-        '"/repo/dplasma_tpu/kernels/quant.py" source_line=77}\n'
-        '  ROOT %r = s8[4,4]{1,0} copy(s8[4,4]{1,0} %convert.1)\n'
-        '}\n')
+        '  %convert.1 = s8[4,4]{1,0} convert(%p0), '
+        'metadata={op_name="q" stack_frame_id=1}\n'
+        '  ROOT %r = s8[4,4]{1,0} copy(%convert.1)\n'
+        '}\n' + _frames("/repo/dplasma_tpu/kernels/quant.py", 77))
     assert ("kernels/quant.py", "f32", "s8") in hc.DECLARED_DEMOTIONS
     mod = hc.parse_module(text)
     res = hc.HloResult(kernel="quant-site")

@@ -355,6 +355,33 @@ def test_capture_compiled_fields():
     assert json.loads(json.dumps(info)) == info
 
 
+@pytest.mark.parametrize("platform,peak", [("tpu", 7), ("cpu", 1 + 2 + 4)])
+def test_capture_compiled_peak_by_platform(platform, peak):
+    """Off the CPU the peak is XLA's own figure; the CPU's leaves the
+    temps out, so there it is args + outputs + temps."""
+    class _Dev:
+        pass
+
+    class _Sharding:
+        device_set = {_Dev()}
+
+    class _Mem:
+        argument_size_in_bytes, output_size_in_bytes = 1, 2
+        temp_size_in_bytes, peak_memory_in_bytes = 4, 7
+
+    class _Compiled:
+        input_shardings = ((_Sharding(),), {})
+        output_shardings = _Sharding()
+
+        def cost_analysis(self):
+            return None
+
+        def memory_analysis(self):
+            return _Mem()
+    next(iter(_Sharding.device_set)).platform = platform
+    assert capture_compiled(_Compiled())["peak_bytes"] == peak
+
+
 def test_capture_compiled_never_raises():
     class Broken:
         def cost_analysis(self):

@@ -4,28 +4,18 @@ The reference's hot bodies are cuBLAS calls inside JDF chores
 (src/zgemm_NN_gpu.jdf, src/zpotrf_L.jdf:432-470); here the TPU analogues
 are Pallas kernels checked against the plain XLA path.
 
-The module runs where the session-level pallas probes pass
-(conftest): the panel kernels need only the INTERPRET probe
-(``requires_pallas_interpret`` — bare pallas_call round-trip; the tpu
-namespace differences are absorbed by ``kernels.pallas_compat``),
-while the gridded GEMM kernels additionally need the grid/scratch/
-compiler-params surface (``requires_pallas``). These tests *execute*
-kernels, so an incompatible pallas must skip them, not fail them. The
-static contracts of the same kernels are checked everywhere by
-``analysis.palcheck`` (tests/test_palcheck.py), which needs no
-runtime.
+The kernels run in interpret mode here; tests/test_chip_compile.py
+compiles them for the chip. Their static contracts are checked by
+``analysis.palcheck`` (tests/test_palcheck.py).
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import requires_pallas, requires_pallas_interpret
 from dplasma_tpu.kernels import blas as k
 
-pk = pytest.importorskip("dplasma_tpu.kernels.pallas_kernels")
-
-pytestmark = requires_pallas_interpret
+from dplasma_tpu.kernels import pallas_kernels as pk
 
 
 @pytest.fixture
@@ -36,7 +26,6 @@ def mats(rng):
     return a, b, c
 
 
-@requires_pallas
 def test_gemm_fused_matches_reference(mats):
     a, b, c = mats
     out = pk.gemm(a, b, c, alpha=2.0, beta=-0.5, bm=128, bn=128, bk=128)
@@ -45,7 +34,6 @@ def test_gemm_fused_matches_reference(mats):
     assert np.allclose(np.asarray(out), ref, atol=1e-3)
 
 
-@requires_pallas
 def test_matmul_beta_zero(mats):
     a, b, _ = mats
     out = pk.matmul(a, b, bm=128, bn=128, bk=64)
@@ -53,7 +41,6 @@ def test_matmul_beta_zero(mats):
     assert np.allclose(np.asarray(out), ref, atol=1e-3)
 
 
-@requires_pallas
 def test_block_clamping_small_problem(rng):
     # Problem smaller than the block quantum: single-block path.
     a = jnp.asarray(rng.standard_normal((64, 48)), jnp.float32)
@@ -64,7 +51,6 @@ def test_block_clamping_small_problem(rng):
     assert np.allclose(np.asarray(out), ref, atol=1e-4)
 
 
-@requires_pallas
 def test_blas_dispatch_toggle(mats):
     a, b, c = mats
     base = k.gemm(1.5, a, b, 0.5, c)
@@ -86,7 +72,6 @@ def test_blas_dispatch_toggle(mats):
     assert np.allclose(np.asarray(fused), np.asarray(base), atol=1e-3)
 
 
-@requires_pallas
 def test_bf16_inputs(rng):
     a = jnp.asarray(rng.standard_normal((128, 128)), jnp.bfloat16)
     b = jnp.asarray(rng.standard_normal((128, 128)), jnp.bfloat16)

@@ -18,16 +18,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from conftest import requires_pallas_interpret
-
 from dplasma_tpu.analysis import spmdcheck as sp
 from dplasma_tpu.kernels import pallas_ring as pring
 from dplasma_tpu.utils import config
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def _mesh1d(n, name="x"):
@@ -38,7 +33,6 @@ def _mesh1d(n, name="x"):
 # interpret-mode execution: the 1x4 simulated ring
 # ---------------------------------------------------------------------
 
-@requires_pallas_interpret
 def test_shift_one_hop_moves_payload_right():
     """One ring_shift hop: rank r's block lands on rank (r+1) % 4 —
     the send/wait pairing of the canonical ring step, executed."""
@@ -50,7 +44,7 @@ def test_shift_one_hop_moves_payload_right():
         lambda a: pring.ring_shift(a, axis="x", axes=(("x", n),),
                                    interpret=True),
         mesh=mesh, in_specs=P("x"), out_specs=P("x"),
-        check_rep=False))
+        check_vma=False))
     y = np.asarray(f(x))
     xs = np.asarray(x)
     for r in range(n):
@@ -59,7 +53,6 @@ def test_shift_one_hop_moves_payload_right():
                               xs[src * rows:(src + 1) * rows])
 
 
-@requires_pallas_interpret
 def test_shift_round_trip_on_1x4_ring():
     """Payload round-trip: four hops around the 1x4 ring return every
     rank's block unchanged — the full-circle send/wait pairing."""
@@ -75,11 +68,10 @@ def test_shift_round_trip_on_1x4_ring():
         return a
 
     f = jax.jit(shard_map(body, mesh=mesh, in_specs=P("x"),
-                          out_specs=P("x"), check_rep=False))
+                          out_specs=P("x"), check_vma=False))
     assert np.array_equal(np.asarray(f(x)), np.asarray(x))
 
 
-@requires_pallas_interpret
 def test_allreduce_matches_sum():
     """The winner-row exchange primitive: the n-1 shift-and-add ring
     sum equals the reduction it replaces (up to the usual f32
@@ -96,7 +88,7 @@ def test_allreduce_matches_sum():
         lambda a: pring.ring_allreduce(a, axis="x", axes=(("x", n),),
                                        interpret=True),
         mesh=mesh, in_specs=P("x"), out_specs=P("x"),
-        check_rep=False))
+        check_vma=False))
     y = np.asarray(f(x))
     want = np.asarray(x).reshape(n, rows, cols).sum(axis=0)
     for r in range(n):
@@ -104,7 +96,6 @@ def test_allreduce_matches_sum():
                                    rtol=2e-4, atol=1e-5)
 
 
-@requires_pallas_interpret
 def test_allreduce_disjoint_exact():
     """Disjoint-support contributions (each row nonzero on exactly
     one rank — the winner-row exchange's shape) sum EXACTLY: the ring
@@ -122,7 +113,7 @@ def test_allreduce_disjoint_exact():
         lambda a: pring.ring_allreduce(a, axis="x", axes=(("x", n),),
                                        interpret=True),
         mesh=mesh, in_specs=P("x"), out_specs=P("x"),
-        check_rep=False))
+        check_vma=False))
     y = np.asarray(f(jnp.asarray(x)))
     for r in range(n):
         assert np.array_equal(y[r * rows:(r + 1) * rows], full)

@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import mca_overrides, requires_pallas_interpret
+from conftest import mca_overrides
 from dplasma_tpu.descriptors import Dist, TileMatrix
 from dplasma_tpu.kernels import householder as hh
 from dplasma_tpu.kernels import panels
@@ -64,13 +64,12 @@ def test_panel_kernel_resolution():
         assert panels.panel_kernel("lu") == "chain"
 
 
-def test_panel_kernel_pallas_degrades(monkeypatch):
-    """panel.kernel=pallas must resolve to the XLA tree/rec paths when
-    the pallas runtime is absent (the win lands everywhere)."""
-    monkeypatch.setattr(panels, "_pallas_ready", lambda route: False)
+def test_panel_kernel_pallas_explicit():
+    """panel.kernel=pallas selects the fused kernels of the two routes
+    that have one, on any backend (interpret mode off the chip)."""
     with mca({"panel.kernel": "pallas"}):
-        assert panels.panel_kernel("qr") == "tree"
-        assert panels.panel_kernel("lu") == "rec"
+        assert panels.panel_kernel("qr") == "pallas"
+        assert panels.panel_kernel("lu") == "pallas"
 
 
 # ------------------------------------------------------- TSQR tree
@@ -424,7 +423,6 @@ def test_phase_model_prices_tree_panel():
 
 # ------------------------------------------------ pallas panel (qr)
 
-@requires_pallas_interpret
 def test_pallas_geqrt_panel_matches_vendor(rng):
     from dplasma_tpu.kernels import pallas_qr
     for m, n in ((48, 16), (64, 8), (32, 32)):
@@ -441,7 +439,6 @@ def test_pallas_geqrt_panel_matches_vendor(rng):
             1.0, np.abs(R0).max()), (m, n)
 
 
-@requires_pallas_interpret
 def test_pallas_geqrt_zero_column(rng):
     from dplasma_tpu.kernels import pallas_qr
     a = np.asarray(rng.standard_normal((32, 8)), np.float32)
@@ -452,7 +449,6 @@ def test_pallas_geqrt_zero_column(rng):
     assert np.isfinite(np.asarray(packed)).all()
 
 
-@requires_pallas_interpret
 def test_pallas_qr_eligibility_gate(rng):
     from dplasma_tpu.kernels import pallas_qr
     ok = jnp.zeros((64, 16), jnp.float32)

@@ -128,7 +128,7 @@ def test_geqrf_dd_route_lookahead_equivalent(la, agg):
 def test_getrf_dd_eager_lookahead_and_fused_flush():
     """The eager dd LU route (> 8 panels): lookahead matches the
     serialized baseline (pivots included), and lu.agg_depth's fused
-    far flushes are IDENTICAL to per-step flushes (pure dispatch
+    far flushes match per-step flushes to rounding (pure dispatch
     fusion — same op order, unlike QR's reassociating aggregation).
     One shared 160^2 dd matrix: these factorizations cost ~10s each,
     so the two properties share the la=1 baselines (tier-1 budget)."""
@@ -147,10 +147,14 @@ def test_getrf_dd_eager_lookahead_and_fused_flush():
     d0 = np.asarray(F0.to_dense())
     assert np.abs(np.asarray(F1.to_dense()) - d0).max() \
         <= 1e-12 * max(np.abs(d0).max(), 1.0)
-    # dispatch fusion: bit-identical to the per-step la=1 result
+    # dispatch fusion: same pivots and op order as the per-step la=1
+    # result; XLA compiles the fused program on its own terms (it
+    # contracts across the fused steps), so agreement is to a few
+    # roundings (5e-15 of max|F| measured, PR 21)
     assert (np.asarray(p4) == np.asarray(p1)).all()
-    assert (np.asarray(F4.to_dense())
-            == np.asarray(F1.to_dense())).all()
+    d1 = np.asarray(F1.to_dense())
+    assert np.abs(np.asarray(F4.to_dense()) - d1).max() \
+        <= 2e-14 * max(np.abs(d1).max(), 1.0)
 
 
 def test_potrf_dd_route_ignores_lookahead():

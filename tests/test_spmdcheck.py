@@ -16,10 +16,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from dplasma_tpu.analysis import spmdcheck as sp
 from dplasma_tpu.descriptors import Dist
@@ -307,7 +304,7 @@ def test_mutation_collective_in_while(devices8):
 
     fn = shard_map(body, mesh=m, in_specs=P(pmesh.ROW_AXIS),
                    out_specs=P(pmesh.ROW_AXIS, None),
-                   check_rep=False)  # while has no replication rule
+                   check_vma=False)  # while has no replication rule
     res = sp.extract_schedule(fn, jnp.zeros((4, 4)), kernel="whilek")
     assert not res.ok
     (d,) = [d for d in res.diagnostics

@@ -2,6 +2,7 @@
 noise-calibrated changepoint detector, provenance stamping (schema
 v18), the perfboard dashboard/CI gate, and perfdiff's
 --auto-threshold integration."""
+import glob
 import json
 import os
 import random
@@ -20,6 +21,10 @@ import perfboard  # noqa: E402
 from tools import perfdiff  # noqa: E402
 
 _ROOT = os.path.join(os.path.dirname(__file__), "..")
+#: fixture ledger and artifacts (shapes of the BENCH_r04/r05 and
+#: SERVEBENCH_r0* records; the bench series carries sgetrf rows)
+_DATA = os.path.join(os.path.dirname(__file__), "data")
+_LEDGER = os.path.join(_DATA, "ledger.jsonl")
 
 
 def _noisy(base, n, frac, seed, step_at=None, step=0.0):
@@ -138,19 +143,22 @@ def test_ledger_fragments_are_named_not_fatal(tmp_path):
 
 
 def test_repo_ledger_and_artifacts_ingest():
-    """The committed ledger and every committed artifact load through
-    the observatory without error."""
-    series, notes = trend.ingest_ledger(
-        os.path.join(_ROOT, "bench_history.jsonl"))
+    """The fixture ledger and every fixture and committed artifact
+    load through the observatory without error."""
+    series, notes = trend.ingest_ledger(_LEDGER)
     assert series
     assert all("family" in s for s in
                (v for v in series.values()))
-    for name in ("BENCH_r01.json", "BENCH_r03.json",
-                 "MULTICHIP_r01.json", "MULTICHIP_SCALING.json",
-                 "SERVEBENCH_r02.json"):
-        docs, art_notes = trend.load_artifact(
-            os.path.join(_ROOT, name))
+    paths = sorted(glob.glob(os.path.join(_DATA, "*.json"))) + [
+        os.path.join(_ROOT, name) for name in (
+            "BENCH_r04.json", "BENCH_r05.json", "MULTICHIP_SCALING.json",
+            "SERVEBENCH_r01.json", "SERVEBENCH_r02.json")]
+    skipped = 0
+    for path in paths:
+        docs, art_notes = trend.load_artifact(path)
         assert docs or art_notes  # loaded or skipped WITH a note
+        skipped += not docs
+    assert skipped == 2  # the timed-out wrapper and the smoke bit
 
 
 # ------------------------------------------------------- provenance
@@ -206,12 +214,10 @@ def test_mca_snapshot_is_the_active_override_set(monkeypatch):
 # -------------------------------------------------------- perfboard
 
 def test_perfboard_renders_and_checks_green(tmp_path):
-    """The dashboard renders from the repo ledger (sparklines,
+    """The dashboard renders from the fixture ledger (sparklines,
     provenance tooltips) and the CI gate is green on it."""
     out = str(tmp_path / "pb.html")
-    rc = perfboard.main(["--ledger",
-                         os.path.join(_ROOT, "bench_history.jsonl"),
-                         "--check", "--out", out])
+    rc = perfboard.main(["--ledger", _LEDGER, "--check", "--out", out])
     assert rc == 0
     text = open(out).read()
     assert "<svg" in text and "perfboard" in text
@@ -219,12 +225,11 @@ def test_perfboard_renders_and_checks_green(tmp_path):
 
 
 def test_perfboard_injected_regression_flips_gate(tmp_path, capsys):
-    """Acceptance: copy the repo ledger, append a synthetic 20%
+    """Acceptance: copy the fixture ledger, append a synthetic 20%
     regression on one bench series -> exit 1 naming the series AND
     the changepoint index."""
-    src = os.path.join(_ROOT, "bench_history.jsonl")
     led = str(tmp_path / "h.jsonl")
-    lines = open(src).read().splitlines()
+    lines = open(_LEDGER).read().splitlines()
     target = None
     for ln in lines:
         d = json.loads(ln)

@@ -37,7 +37,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import pathlib
 import sys
 
@@ -70,14 +69,10 @@ def _db_arg(ns) -> str:
 
 def cmd_sweep(ns) -> int:
     import jax
-    # the sweep is compile-dominated: ride the same persistent XLA
-    # cache bench.py and the test suite use
-    if not jax.config.jax_compilation_cache_dir:
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            os.environ.get("DPLASMA_XLA_CACHE", str(_ROOT / ".jax_cache")))
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", 0.5)
+
+    from dplasma_tpu.utils.config import use_compile_cache
+    # the sweep is compile-dominated: ride the persistent XLA cache
+    use_compile_cache()
     if ns.dtype in ("float64", "complex128"):
         jax.config.update("jax_enable_x64", True)
     from dplasma_tpu.observability import roofline as _rl

@@ -439,12 +439,6 @@ def run_serving_smoke() -> int:
     from dplasma_tpu.serving import batched
     from dplasma_tpu.serving import cache as scache
 
-    # ride the same persistent compile cache the test suite uses (a
-    # no-op under pytest where conftest already configured it)
-    if not jax.config.jax_compilation_cache_dir:
-        jax.config.update("jax_compilation_cache_dir",
-                          str(_ROOT / ".jax_cache"))
-
     @functools.partial(jax.jit, static_argnums=(0, 3))
     def _solve(op, a, b, nb):
         x, _ = batched.solve_batched(op, a, b, nb)
@@ -516,9 +510,6 @@ def run_hlocheck_smoke() -> int:
     from dplasma_tpu.parallel import cyclic
     from dplasma_tpu.parallel import mesh as pmesh
 
-    if not jax.config.jax_compilation_cache_dir:
-        jax.config.update("jax_compilation_cache_dir",
-                          str(_ROOT / ".jax_cache"))
     nb, nt = 4, 4
     bad = 0
     P, Q = 2, 2
@@ -603,9 +594,6 @@ def run_ring_smoke() -> int:
     from dplasma_tpu.parallel import mesh as pmesh
     from dplasma_tpu.utils import config as _cfg
 
-    if not jax.config.jax_compilation_cache_dir:
-        jax.config.update("jax_compilation_cache_dir",
-                          str(_ROOT / ".jax_cache"))
     bad = 0
     for P, Q in ((2, 2), (1, 4), (2, 4), (4, 2)):
         for name, prog in pring.kernel_programs(P, Q).items():
@@ -665,9 +653,6 @@ def run_tune_smoke() -> int:
     from dplasma_tpu.tuning import TuningDB, make_key, search
     from dplasma_tpu.utils import config as _cfg
 
-    if not jax.config.jax_compilation_cache_dir:
-        jax.config.update("jax_compilation_cache_dir",
-                          str(_ROOT / ".jax_cache"))
     bad = 0
     with tempfile.TemporaryDirectory() as td:
         dbp = f"{td}/tune_db.json"
@@ -750,9 +735,6 @@ def run_quant_smoke() -> int:
     from dplasma_tpu.tuning import TuningDB
     from dplasma_tpu.tuning import autopilot as _ap
 
-    if not jax.config.jax_compilation_cache_dir:
-        jax.config.update("jax_compilation_cache_dir",
-                          str(_ROOT / ".jax_cache"))
     jax.config.update("jax_enable_x64", True)
     bad = 0
     rng = np.random.default_rng(3872)
@@ -847,9 +829,6 @@ def run_telemetry_smoke() -> int:
                                                   load_report)
     from dplasma_tpu.serving import SolverService
 
-    if not jax.config.jax_compilation_cache_dir:
-        jax.config.update("jax_compilation_cache_dir",
-                          str(_ROOT / ".jax_cache"))
     bad = 0
     rng = np.random.default_rng(3872)
     n, nrhs = 6, 2
@@ -951,9 +930,6 @@ def run_soak_smoke() -> int:
     from dplasma_tpu.resilience import inject
     from dplasma_tpu.serving import AdmissionError, SolverService
 
-    if not jax.config.jax_compilation_cache_dir:
-        jax.config.update("jax_compilation_cache_dir",
-                          str(_ROOT / ".jax_cache"))
     bad = 0
     rng = np.random.default_rng(3872)
     n, nrhs = 6, 2
@@ -1197,13 +1173,11 @@ def run_devprof_smoke() -> int:
 
 def run_trend_smoke() -> int:
     """The perf-observatory invariants that must hold on EVERY
-    commit: the trend model ingests the repo's own ledger and every
-    committed artifact without error; the changepoint detector finds
-    a clean synthetic step at exactly its index (and nothing else);
-    perfboard renders the dashboard and its ``--check`` gate is
-    green on the repo ledger. A red gate here means the repo itself
-    carries an unexplained regression — that is a lint failure, not
-    background noise."""
+    commit: the trend model ingests the fixture ledger and every
+    fixture artifact under tests/data without error; the changepoint
+    detector finds a clean synthetic step at exactly its index (and
+    nothing else); perfboard renders the dashboard and its
+    ``--check`` gate is green on the fixture ledger."""
     import importlib.util
     import tempfile
 
@@ -1221,10 +1195,9 @@ def run_trend_smoke() -> int:
     trend = _load("_lint_trend", "dplasma_tpu/observability/trend.py")
     perfboard = _load("_lint_perfboard", "tools/perfboard.py")
     bad = 0
-    # 1) every committed artifact loads (or is skipped with a note)
-    for path in sorted(_ROOT.glob("*.json")):
-        if path.name == "BASELINE.json":
-            continue
+    # 1) every fixture artifact loads (or is skipped with a note)
+    data = _ROOT / "tests" / "data"
+    for path in sorted(data.glob("*.json")):
         try:
             docs, notes = trend.load_artifact(path)
         except (OSError, ValueError) as exc:
@@ -1235,8 +1208,8 @@ def run_trend_smoke() -> int:
             sys.stderr.write(f"trend-smoke: {path.name}: neither "
                              f"docs nor a skip note\n")
             bad += 1
-    # 2) the repo ledger ingests; fragments are named, never fatal
-    ledger = _ROOT / "bench_history.jsonl"
+    # 2) the fixture ledger ingests; fragments are named, never fatal
+    ledger = data / "ledger.jsonl"
     if ledger.exists():
         try:
             series, notes = trend.ingest_ledger(ledger)
@@ -1245,8 +1218,8 @@ def run_trend_smoke() -> int:
                              f"{exc}\n")
             return bad + 1
         if not series:
-            sys.stderr.write("trend-smoke: repo ledger produced no "
-                             "series\n")
+            sys.stderr.write("trend-smoke: fixture ledger produced "
+                             "no series\n")
             bad += 1
     # 3) detector golden: a clean 20% step at index 12, found once
     values = [100.0 + (0.4 if i % 2 else -0.4) for i in range(12)] \
@@ -1256,7 +1229,7 @@ def run_trend_smoke() -> int:
         sys.stderr.write(f"trend-smoke: step-at-12 golden found "
                          f"{[c['index'] for c in cps]}\n")
         bad += 1
-    # 4) perfboard renders and the CI gate is green on the repo ledger
+    # 4) perfboard renders and the CI gate is green on the fixture
     if ledger.exists():
         with tempfile.TemporaryDirectory() as td:
             out = f"{td}/pb.html"
@@ -1264,7 +1237,7 @@ def run_trend_smoke() -> int:
                                  "--check", "--out", out])
             if rc != 0:
                 sys.stderr.write(f"trend-smoke: perfboard --check "
-                                 f"rc={rc} on the repo ledger\n")
+                                 f"rc={rc} on the fixture ledger\n")
                 bad += 1
             else:
                 with open(out) as f:
@@ -1278,6 +1251,8 @@ def run_trend_smoke() -> int:
 
 
 def main(argv=None) -> int:
+    from dplasma_tpu.utils.config import use_compile_cache
+    use_compile_cache()   # the smokes compile small graphs
     pkg = _ROOT / "dplasma_tpu"
     bad = 0
     for name, fn in (("lint_excepts", lambda: run_excepts(pkg)),

@@ -57,17 +57,6 @@ _ROOT = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(_ROOT))
 sys.path.insert(0, str(_ROOT / "tools"))
 
-# an 8-chip curve needs 8 devices: force the virtual CPU platform
-# BEFORE jax imports (a no-op when jax is already in, e.g. pytest —
-# tests/conftest.py did the same thing earlier)
-if "jax" not in sys.modules:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    _flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in _flags:
-        os.environ["XLA_FLAGS"] = (
-            _flags + " --xla_force_host_platform_device_count=8"
-        ).strip()
-
 #: op -> precision letter of the measured trial (f64 cyclic kernels)
 _OPS = {"potrf": "d", "getrf": "d", "geqrf": "d"}
 
@@ -246,11 +235,9 @@ def main(argv=None) -> int:
     ns = ap.parse_args(argv)
 
     import jax
-    if not jax.config.jax_compilation_cache_dir:
-        jax.config.update("jax_compilation_cache_dir",
-                          str(_ROOT / ".jax_cache"))
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", 0.5)
+
+    from dplasma_tpu.utils.config import use_compile_cache
+    use_compile_cache()
     jax.config.update("jax_enable_x64", True)
     bad = [op for op in ns.ops if op not in _OPS]
     if bad:

@@ -229,10 +229,8 @@ def render(series_map: dict, verdicts: dict, notes: List[str],
 def main(argv: Optional[list] = None) -> int:
     ap = argparse.ArgumentParser(
         prog="perfboard", description=__doc__.splitlines()[0])
-    ap.add_argument("--ledger", default=str(_ROOT
-                                            / "bench_history.jsonl"),
-                    help="bench_history.jsonl to render (default: the "
-                         "repo ledger)")
+    ap.add_argument("--ledger", required=True,
+                    help="the JSONL ledger to render")
     ap.add_argument("--out", default=None, metavar="HTML",
                     help="write the dashboard here (default "
                          "perfboard.html unless --check)")

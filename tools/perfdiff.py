@@ -33,8 +33,7 @@ Ledger envelope: every current writer stamps its documents with a
 ``"family"`` key (run-reports carry ``schema`` + ``name`` instead).
 Envelope-less fragments from pre-envelope vintages are skipped by
 :func:`latest_comparable_entry` with a named note on stderr — never
-crashed on, never silently adopted as a baseline
-(``tools/ledger_backfill.py`` upgrades an old ledger in place).
+crashed on, never silently adopted as a baseline.
 
 Comparable metrics extracted from each document:
 
@@ -206,7 +205,7 @@ def latest_comparable_entry(path: str, doc: dict) -> Optional[dict]:
                 sys.stderr.write(
                     f"perfdiff: note: {path}:{lineno}: envelope-less "
                     f"ledger fragment (no family/schema key) skipped "
-                    f"as baseline; run tools/ledger_backfill.py\n")
+                    f"as baseline\n")
                 continue
             if entry.get("tuning") is True and not tuning_doc:
                 # a production gate must never baseline against a

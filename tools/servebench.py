@@ -23,7 +23,7 @@ then records:
 
 Everything lands in a run-report ``"serving"`` section (``--report``;
 schema v13 adds the ``"telemetry"`` section — span ledger, flight
-recorder), in the ``bench_history.jsonl`` ledger (``--history`` /
+recorder), in a JSONL ledger when one is given (``--history`` /
 ``DPLASMA_BENCH_HISTORY``), and — with ``--gate`` — is compared
 against the newest prior ledger entry by ``tools/perfdiff.py``
 (latency entries declare ``"better": "lower"``; a baseline predating
@@ -321,8 +321,8 @@ def main(argv=None) -> int:
     ap.add_argument("--report", default=None,
                     help="write the v8 run-report here")
     ap.add_argument("--history", default=None,
-                    help="bench_history.jsonl ledger (default env "
-                         "DPLASMA_BENCH_HISTORY or bench_history.jsonl)")
+                    help="JSONL ledger to gate against and append to "
+                         "(default: $DPLASMA_BENCH_HISTORY; none)")
     ap.add_argument("--gate", action="store_true",
                     help="compare against the newest prior ledger "
                          "entry with tools/perfdiff.py")
@@ -601,10 +601,9 @@ def main(argv=None) -> int:
                 print(f"# report written to {ns.report}")
 
         import perfdiff
-        history = ns.history or os.environ.get("DPLASMA_BENCH_HISTORY",
-                                               "bench_history.jsonl")
+        history = ns.history or os.environ.get("DPLASMA_BENCH_HISTORY")
         prev = None
-        if os.path.exists(history):
+        if history and os.path.exists(history):
             try:
                 # newest SERVING-family entry (the ledger may interleave
                 # bench.py ladder docs with no common metrics)
@@ -613,7 +612,8 @@ def main(argv=None) -> int:
                 print(f"#! cannot read bench history: {exc}",
                       file=sys.stderr)
         try:
-            perfdiff.append_ledger(history, doc)
+            if history:
+                perfdiff.append_ledger(history, doc)
         except OSError as exc:
             print(f"#! cannot append bench history: {exc}",
                   file=sys.stderr)
