@@ -69,12 +69,14 @@ def _split_int(x, w: int, nl: int, axis: int):
     scale derives from (callers use it for NaN/Inf detection without
     an extra pass).
     """
-    ax = 1 - axis  # reduce over the opposite axis
-    m = jnp.max(jnp.abs(x), axis=ax, keepdims=True)
-    # strictly-greater power-of-two scale: |u| < 1 keeps every digit
-    # <= 2^w - 1 = 127 (u = +-1 would emit +-128, wrapping int8)
-    scale = _pow2_scale_bits(m)
-    return _split_fixed(x, scale, w, nl), scale, m
+    from dplasma_tpu.observability import phases
+    with phases.span("split", timed=False):
+        ax = 1 - axis  # reduce over the opposite axis
+        m = jnp.max(jnp.abs(x), axis=ax, keepdims=True)
+        # strictly-greater power-of-two scale: |u| < 1 keeps every digit
+        # <= 2^w - 1 = 127 (u = +-1 would emit +-128, wrapping int8)
+        scale = _pow2_scale_bits(m)
+        return _split_fixed(x, scale, w, nl), scale, m
 
 
 def _level_recombine(levels, w: int):
@@ -200,16 +202,18 @@ def _recombine_scale_base(levels, base, sa, sb, w: int):
     (kernels/pallas_dd.py; profiled r5 at ~60% of the blocked-dd
     panel IR and half the trailing-update time when left to the x64
     rewriter's emulated chain); elsewhere the exact emulated
-    recombine."""
-    if base is None and not isinstance(sa, jax.Array):
-        sa = jnp.asarray(sa)
-    N = levels[0].shape[1]
-    if _pallas_epilogue_ok(levels, N):
-        from dplasma_tpu.kernels import pallas_dd
-        return pallas_dd.recombine_base(levels, base, sa, sb, w)
-    U = _level_recombine(levels, w)
-    prod = U * (sa * sb)
-    return -prod if base is None else base - prod
+    recombine. Either route runs under the ``recombine`` scope."""
+    from dplasma_tpu.observability import phases
+    with phases.span("recombine", timed=False):
+        if base is None and not isinstance(sa, jax.Array):
+            sa = jnp.asarray(sa)
+        N = levels[0].shape[1]
+        if _pallas_epilogue_ok(levels, N):
+            from dplasma_tpu.kernels import pallas_dd
+            return pallas_dd.recombine_base(levels, base, sa, sb, w)
+        U = _level_recombine(levels, w)
+        prod = U * (sa * sb)
+        return -prod if base is None else base - prod
 
 
 def gemm_residual(base, a, b, bits: int = 53):
@@ -709,9 +713,11 @@ def _cache_write(W, limbs, s: int):
     the MXU (measured r5: 9x on early skinny-K steps). Row extent is
     clipped inside the executable, so no eager slice of a big array
     is dispatched on its own."""
-    N = W.shape[2]
-    lim = jax.lax.slice_in_dim(limbs, 0, N - s, axis=2)
-    return jax.lax.dynamic_update_slice(W, lim, (0, s, s))
+    from dplasma_tpu.observability import phases
+    with phases.span("split", timed=False):
+        N = W.shape[2]
+        lim = jax.lax.slice_in_dim(limbs, 0, N - s, axis=2)
+        return jax.lax.dynamic_update_slice(W, lim, (0, s, s))
 
 
 @partial(jax.jit, static_argnums=(3, 4))
@@ -724,17 +730,20 @@ def _jit_panel(slab, scale, s, nb: int, refine: int):
     every panel of every sweep at that size — the r3 unrolled graphs
     recompiled this shape-identical subgraph nt times and the AOT
     helper was OOM-killed at N=8192 (VERDICT r4 item 2)."""
+    from dplasma_tpu.observability import phases
     w, nl, _ = _plan(slab.shape[0], 53)
     sc = jnp.roll(scale, -s, axis=0)
-    Lkk, _ = _potrf_tile_ir(slab[:nb], refine=refine,
-                            need_inverse=False)
-    pan = _panel_trsm_ir(Lkk, slab[nb:])
-    colL = jnp.concatenate([Lkk, pan], axis=0)
+    with phases.span("panel", timed=False):
+        Lkk, _ = _potrf_tile_ir(slab[:nb], refine=refine,
+                                need_inverse=False)
+        pan = _panel_trsm_ir(Lkk, slab[nb:])
+        colL = jnp.concatenate([Lkk, pan], axis=0)
     # split the TRANSPOSE: the cache stores Wt[l, col, row], and an
     # explicit post-split int8 transpose measured ~95 ms/step at
     # N=16384 (byte-granularity shuffles); transposing the f64 operand
     # fuses into the split's elementwise chain instead
-    limbs = jnp.stack(_split_fixed(colL.T, sc[:, 0][None, :], w, nl))
+    with phases.span("split", timed=False):
+        limbs = jnp.stack(_split_fixed(colL.T, sc[:, 0][None, :], w, nl))
     return colL, limbs
 
 
@@ -746,16 +755,20 @@ def _jit_slab0(A, nb: int):
 @partial(jax.jit, static_argnums=(2, 3), donate_argnums=(0,))
 def _jit_colwrite(out, colL, s: int, nb: int):
     """Write finished column block (rows clipped) into the result."""
-    N = out.shape[0]
-    c = jax.lax.slice_in_dim(colL, 0, N - s, axis=0)
-    return jax.lax.dynamic_update_slice(out, c, (s, s))
+    from dplasma_tpu.observability import phases
+    with phases.span("assemble", timed=False):
+        N = out.shape[0]
+        c = jax.lax.slice_in_dim(colL, 0, N - s, axis=0)
+        return jax.lax.dynamic_update_slice(out, c, (s, s))
 
 
 @partial(jax.jit, static_argnums=(1,))
 def _jit_tile(slab, refine: int):
+    from dplasma_tpu.observability import phases
     nb = slab.shape[1]
-    return _potrf_tile_ir(slab[:nb], refine=refine,
-                          need_inverse=False)[0]
+    with phases.span("panel", timed=False):
+        return _potrf_tile_ir(slab[:nb], refine=refine,
+                              need_inverse=False)[0]
 
 
 @partial(jax.jit, static_argnums=(3, 4))
@@ -766,16 +779,19 @@ def _jit_trail(A, W, scale, s: int, nb: int):
     column band Wt[:, :s, s:]. Full arrays in, slicing INSIDE the
     executable (no eager big-array slice dispatched on its own); one
     executable per s."""
+    from dplasma_tpu.observability import phases
     N = A.shape[0]
     K = s
     w, nl, kc = _plan(K, 53)
-    band = jax.lax.slice(W, (0, 0, s), (nl, K, N))   # (nl, K, N-s)
-    slabA = jax.lax.slice(A, (s, s), (N, s + nb))
-    out = _pair_dot_base([band[i] for i in range(nl)],
-                         [jax.lax.slice_in_dim(band[i], 0, nb, axis=1)
-                          for i in range(nl)], slabA, scale[s:],
-                         scale[s:s + nb].T, K=K, w=w, nl=nl, kc=kc)
-    return jnp.pad(out, ((0, s), (0, 0)))   # fixed (N, nb) for _jit_panel
+    with phases.span("update", timed=False):
+        band = jax.lax.slice(W, (0, 0, s), (nl, K, N))   # (nl, K, N-s)
+        slabA = jax.lax.slice(A, (s, s), (N, s + nb))
+        out = _pair_dot_base([band[i] for i in range(nl)],
+                             [jax.lax.slice_in_dim(band[i], 0, nb, axis=1)
+                              for i in range(nl)], slabA, scale[s:],
+                             scale[s:s + nb].T, K=K, w=w, nl=nl, kc=kc)
+        # fixed (N, nb) for _jit_panel
+        return jnp.pad(out, ((0, s), (0, 0)))
 
 
 def _potrf_f64_blocked_cached(A, nb: int, refine: int):
@@ -839,6 +855,7 @@ def potrf_f64_blocked(A, nb: int = 512, lower: bool = True,
         # one panel compile reused across all nt panels (the unrolled
         # graph costs ~20s AOT per panel at N=8192 — VERDICT r4 item 2)
         return _potrf_f64_blocked_cached(A, nb, refine)
+    from dplasma_tpu.observability import phases
     w, nl, kc = _plan(N, 53)
     scale = _row_norm_scales(jnp.diag(A))[:, None]
     # preallocated stacked limb cache, TRANSPOSED layout (nl, N-nb, N)
@@ -854,28 +871,34 @@ def potrf_f64_blocked(A, nb: int = 512, lower: bool = True,
         s = k * nb
         slab = A[s:, s:s + nb]
         if k:
-            slab = _pair_dot_base(
-                [W[i, :s, s:] for i in range(nl)],
-                [W[i, :s, s:s + nb] for i in range(nl)], slab,
-                scale[s:], scale[s:s + nb].T, K=s, w=w, nl=nl, kc=kc)
-        Lkk, _ = _potrf_tile_ir(slab[:nb], refine=refine,
-                                need_inverse=False)
-        if s + nb < N:
-            # trsm + exact-residual IR replaces the Newton-inverse
-            # panel (3x fewer exact nb^3 products per column; the op
-            # count, not the flops, bounded the r3 sweep)
-            pan = _panel_trsm_ir(Lkk, slab[nb:])
-            colL = jnp.concatenate([Lkk, pan], axis=0)
-        else:
-            colL = Lkk
+            with phases.span("update", timed=False):
+                slab = _pair_dot_base(
+                    [W[i, :s, s:] for i in range(nl)],
+                    [W[i, :s, s:s + nb] for i in range(nl)], slab,
+                    scale[s:], scale[s:s + nb].T, K=s, w=w, nl=nl,
+                    kc=kc)
+        with phases.span("panel", timed=False):
+            Lkk, _ = _potrf_tile_ir(slab[:nb], refine=refine,
+                                    need_inverse=False)
+            if s + nb < N:
+                # trsm + exact-residual IR replaces the Newton-inverse
+                # panel (3x fewer exact nb^3 products per column; the
+                # op count, not the flops, bounded the r3 sweep)
+                pan = _panel_trsm_ir(Lkk, slab[nb:])
+                colL = jnp.concatenate([Lkk, pan], axis=0)
+            else:
+                colL = Lkk
         cols.append(colL)
         if k + 1 < nt:
-            limbs = jnp.stack(_split_fixed(colL.T, scale[s:].T, w, nl))
-            W = jax.lax.dynamic_update_slice(W, limbs, (0, s, s))
-    out = [jnp.concatenate(
-        [jnp.zeros((j * nb, nb), jnp.float64), c], axis=0)
-        for j, c in enumerate(cols)]
-    return jnp.concatenate(out, axis=1)
+            with phases.span("split", timed=False):
+                limbs = jnp.stack(_split_fixed(colL.T, scale[s:].T, w,
+                                               nl))
+                W = jax.lax.dynamic_update_slice(W, limbs, (0, s, s))
+    with phases.span("assemble", timed=False):
+        out = [jnp.concatenate(
+            [jnp.zeros((j * nb, nb), jnp.float64), c], axis=0)
+            for j, c in enumerate(cols)]
+        return jnp.concatenate(out, axis=1)
 
 
 # ---------------------------------------------------------------------

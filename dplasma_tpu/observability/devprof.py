@@ -8,7 +8,9 @@ PaRSEC's per-task profiling readback:
 
 1. **capture** — :class:`DevprofCapture` wraps the driver's timed
    loop. Backend ``jax`` records a ``jax.profiler`` trace and ingests
-   its Chrome trace events when the runtime writes any; backend
+   the device ops of its ``.xplane.pb`` (the ``XLA Ops`` line of every
+   ``/device:TPU:<n>`` plane, each op with the ``dplasma.*`` named
+   scopes of its op name); backend
    ``synthetic`` (the only one that produces a device timeline on the
    CPU host-platform mesh, where XLA's profiler has no device lanes)
    reconstructs the per-rank timeline from the measured run seconds,
@@ -18,7 +20,9 @@ PaRSEC's per-task profiling readback:
    so the ingestion/attribution contract is testable everywhere.
    ``auto`` picks ``jax`` on accelerator backends and ``synthetic``
    on the CPU mesh (an in-loop profiler capture there is pure
-   overhead with no device events to show for it).
+   overhead with no device events to show for it). A ``jax`` capture
+   that yields no device ops falls back to ``synthetic`` with a
+   ``note`` that says so.
 2. **binning** — timeline ops land in ``compute`` / ``collective`` /
    ``ici`` / ``host`` categories by matching the same HLO op-name
    tables hlocheck parses (:mod:`dplasma_tpu.analysis.hlo_names` —
@@ -49,8 +53,6 @@ measured-ICI evidence on stored autotuner winners
 from __future__ import annotations
 
 import glob
-import gzip
-import json
 import os
 import tempfile
 import threading
@@ -63,8 +65,8 @@ from dplasma_tpu.utils import config as _cfg
 _cfg.mca_register(
     "devprof.backend", "auto",
     "Timeline capture backend for --devprof: jax = wrap the timed "
-    "loop in a jax.profiler trace and ingest its Chrome events when "
-    "the runtime writes any; synthetic = reconstruct the per-rank "
+    "loop in a jax.profiler trace and ingest the device ops of its "
+    ".xplane.pb; synthetic = reconstruct the per-rank "
     "timeline from the measured run + the spmdcheck schedule + the "
     "spmd_comm_model pricing (the CPU-mesh path); auto = jax on "
     "accelerator backends, synthetic on the CPU host platform.")
@@ -95,15 +97,18 @@ def _ici_peak_bps(peaks: Optional[dict]) -> float:
 
 def timeline_op(name: str, rank: int, begin_ns: int, end_ns: int,
                 cls: Optional[str] = None,
-                step: Optional[int] = None) -> dict:
+                step: Optional[int] = None,
+                scope: Tuple[str, ...] = ()) -> dict:
     """One timeline op: a span on one rank's device lane. ``cls`` is
     the collective class key (``kind@axis``, spmdcheck's spelling)
     when known; the category bin always derives from the op *name*
-    (the shared hlocheck vocabulary), never from the class."""
+    (the shared hlocheck vocabulary), never from the class. ``scope``
+    is the op's ``dplasma.*`` named scopes, outermost first
+    (:data:`dplasma_tpu.observability.phases.SCOPES`)."""
     return {"name": str(name), "rank": int(rank),
             "begin_ns": int(begin_ns), "end_ns": int(end_ns),
             "category": timeline_category(name),
-            "cls": cls, "step": step}
+            "cls": cls, "step": step, "scope": tuple(scope)}
 
 
 class DevprofCollector:
@@ -147,31 +152,145 @@ class DevprofCollector:
 # Capture backends
 # ---------------------------------------------------------------------
 
+#: the profiler's device planes and the line that holds their XLA ops
+DEVICE_PLANE = "/device:TPU:"
+OPS_LINE = "XLA Ops"
+#: the event-metadata stat that carries an op's HLO op name
+OP_NAME_STAT = "tf_op"
+
+
+def _varint(buf, i: int) -> Tuple[int, int]:
+    out = shift = 0
+    while True:
+        b = buf[i]
+        i += 1
+        out |= (b & 0x7F) << shift
+        if b < 0x80:
+            return out, i
+        shift += 7
+
+
+def _fields(buf, start: int = 0, end: Optional[int] = None):
+    """(field number, value) of one serialized protobuf message:
+    varints as ints, length-delimited fields as (start, end) offsets
+    into ``buf``, fixed-width fields as their bytes."""
+    i, end = start, len(buf) if end is None else end
+    while i < end:
+        key, i = _varint(buf, i)
+        wire = key & 7
+        if wire == 0:
+            val, i = _varint(buf, i)
+        elif wire == 2:
+            n, i = _varint(buf, i)
+            val, i = (i, i + n), i + n
+        elif wire in (1, 5):
+            n = 8 if wire == 1 else 4
+            val, i = bytes(buf[i:i + n]), i + n
+        else:
+            raise ValueError(f"protobuf wire type {wire} at byte {i}")
+        yield key >> 3, val
+
+
+def _text(buf, span) -> str:
+    return bytes(buf[span[0]:span[1]]).decode("utf-8", "replace")
+
+
+def _entry(buf, span):
+    """(key, value span) of one protobuf map entry."""
+    key = val = None
+    for fn, v in _fields(buf, *span):
+        if fn == 1:
+            key = v
+        elif fn == 2:
+            val = v
+    return key, val
+
+
+def xplane_op_names(raw: bytes) -> Dict[str, Dict[str, str]]:
+    """{device plane name: {event name: HLO op name}} of a serialized
+    XSpace: the ``tf_op`` stat of each event's metadata (string or
+    interned), which ``jax.profiler.ProfileData`` does not expose.
+    Reads XSpace.planes (1) -> XPlane.name (2), event_metadata (4),
+    stat_metadata (5); XEventMetadata.name (2), stats (5); XStat
+    metadata_id (1), str_value (5), ref_value (7)."""
+    buf = memoryview(raw)
+    out: Dict[str, Dict[str, str]] = {}
+    for fn, plane in _fields(buf):
+        if fn != 1:
+            continue
+        name, events, stat_names = "", [], {}
+        for pf, v in _fields(buf, *plane):
+            if pf == 2:
+                name = _text(buf, v)
+            elif pf == 4:
+                meta = _entry(buf, v)[1]
+                if meta:                  # an entry without a value
+                    events.append(meta)
+            elif pf == 5:
+                key, meta = _entry(buf, v)
+                for mf, mv in _fields(buf, *(meta or (0, 0))):
+                    if mf == 2:
+                        stat_names[key] = _text(buf, mv)
+        if not name.startswith(DEVICE_PLANE):
+            continue
+        want = {k for k, v in stat_names.items() if v == OP_NAME_STAT}
+        ops: Dict[str, str] = {}
+        for ev in events:
+            ev_name, op = "", None
+            for ef, ev_val in _fields(buf, *ev):
+                if ef == 2:
+                    ev_name = _text(buf, ev_val)
+                elif ef == 5:
+                    stat = dict(_fields(buf, *ev_val))
+                    if stat.get(1) not in want:
+                        continue
+                    if 5 in stat:
+                        op = _text(buf, stat[5])
+                    elif 7 in stat:
+                        op = stat_names.get(stat[7])
+            if op:
+                ops[ev_name] = op
+        out[name] = ops
+    return out
+
+
+def op_scopes(op_name: str) -> Tuple[str, ...]:
+    """The ``dplasma.*`` named scopes of an HLO op name, outermost
+    first, without the prefix."""
+    from dplasma_tpu.observability.phases import SCOPE_PREFIX
+    return tuple(part.split("[")[0].rstrip(":")[len(SCOPE_PREFIX):]
+                 for part in op_name.split("/")
+                 if part.startswith(SCOPE_PREFIX))
+
+
 def _jax_timeline(logdir: str) -> List[dict]:
-    """Ingest whatever Chrome trace events a ``jax.profiler`` capture
-    left under ``logdir`` (``**/*.trace.json.gz``). Most runtimes
-    write only the raw ``.xplane.pb`` (post-processed elsewhere), so
-    an empty list is the common, non-error answer — the caller falls
-    back to the synthetic backend."""
+    """The device ops of a ``jax.profiler`` capture under ``logdir``:
+    every event of the ``XLA Ops`` line of each ``/device:TPU:<n>``
+    plane of its ``.xplane.pb`` files (read with
+    ``jax.profiler.ProfileData``), on rank ``n``, named by its HLO
+    instruction (``fusion.12``) and carrying its named scopes. An
+    empty list means the capture holds no device ops."""
+    from jax.profiler import ProfileData
     out: List[dict] = []
     for path in sorted(glob.glob(os.path.join(
-            logdir, "**", "*.trace.json.gz"), recursive=True)):
-        try:
-            with gzip.open(path, "rt") as f:
-                doc = json.load(f)
-        except (OSError, ValueError, EOFError):
-            continue
-        for e in (doc or {}).get("traceEvents") or []:
-            if not isinstance(e, dict) or e.get("ph") != "X":
+            logdir, "**", "*.xplane.pb"), recursive=True)):
+        with open(path, "rb") as f:
+            raw = f.read()
+        names = xplane_op_names(raw)
+        for plane in ProfileData.from_serialized_xspace(raw).planes:
+            if not plane.name.startswith(DEVICE_PLANE):
                 continue
-            ts, dur = e.get("ts"), e.get("dur")
-            if not isinstance(ts, (int, float)) \
-                    or not isinstance(dur, (int, float)):
-                continue
-            out.append(timeline_op(e.get("name", "?"),
-                                   int(e.get("pid", 0)),
-                                   int(ts * 1e3),
-                                   int((ts + dur) * 1e3)))
+            rank = int(plane.name[len(DEVICE_PLANE):])
+            ops = names.get(plane.name, {})
+            for line in plane.lines:
+                if line.name != OPS_LINE:
+                    continue
+                for ev in line.events:
+                    hlo = ev.name.split(" = ", 1)[0].strip().lstrip("%")
+                    out.append(timeline_op(
+                        hlo, rank, ev.start_ns,
+                        ev.start_ns + ev.duration_ns,
+                        scope=op_scopes(ops.get(ev.name, ""))))
     return out
 
 
@@ -232,8 +351,10 @@ class DevprofCapture:
             if self.events:
                 self.used = "jax"
             elif not self.note:
-                self.note = ("jax capture produced no Chrome trace "
-                             "events; synthetic timeline used")
+                self.note = ("jax capture holds no device ops (no "
+                             f"{OPS_LINE!r} events on a "
+                             f"{DEVICE_PLANE}<n> plane); synthetic "
+                             "timeline used")
         return False
 
 
@@ -395,14 +516,16 @@ def _critical_path(spans: List[dict], run_s: float,
     import bisect
     ordered = sorted(spans, key=lambda s: s["end_ns"])
     ends = [s["end_ns"] for s in ordered]
-    cur = ordered[-1]
-    chain = [cur]
+    at = len(ordered) - 1
+    chain = [ordered[at]]
     while True:
-        i = bisect.bisect_right(ends, cur["begin_ns"])
-        if i == 0:
+        # only spans ordered before the current one: a zero-length op
+        # (a captured event shorter than the clock's tick) ends where
+        # it begins and would otherwise be its own predecessor
+        at = bisect.bisect_right(ends, ordered[at]["begin_ns"], 0, at) - 1
+        if at < 0:
             break
-        cur = ordered[i - 1]
-        chain.append(cur)
+        chain.append(ordered[at])
     chain.reverse()
     length_s = sum((s["end_ns"] - s["begin_ns"]) for s in chain) / 1e9
     rows = [{"name": s["name"], "rank": s["rank"],

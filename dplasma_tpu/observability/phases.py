@@ -23,11 +23,24 @@ fusion/overlap behavior bit-for-bit (asserted by
 through untouched, and the ledger is only ever activated around eager
 execution.
 
+Named scopes: every span also opens ``jax.named_scope("dplasma.<name>")``
+around its body, profiling or not. A named scope is trace-time metadata:
+each HLO instruction emitted inside it carries ``dplasma.<name>`` in its
+``op_name`` (outermost scope first), and so does each device op of a
+profiler trace, so the phases of a compiled program can be read from its
+trace. It changes no instruction. The names form one vocabulary,
+:data:`SCOPES`. A span with ``timed=False`` is a scope only: the ledger
+neither times nor fences it (the boundaries inside compiled programs
+that the eager attributed pass does not price).
+
 Usage (instrumented code)::
 
     with phases.span("panel") as fence:
         pack, state = panel(col)
         fence((pack, state))      # fenced at exit iff profiling is on
+
+    with phases.span("update", timed=False):
+        slab = trailing_update(...)
 
 Usage (harness)::
 
@@ -40,6 +53,25 @@ from __future__ import annotations
 import contextlib
 import time
 from typing import Dict, List, Optional
+
+
+#: every span name: each opens the named scope ``dplasma.<name>``
+SCOPES = (
+    # factorizations and their sweeps
+    "potrf", "getrf", "panel", "update", "lookahead", "far_flush",
+    "catchup", "assemble",
+    # the dd engine's limb scheme around its int8 products
+    "split", "recombine",
+    # solves
+    "solve", "laswp",
+    # the distributed LU: layout changes and the tournament panel
+    "redistribute", "bcast", "elect", "playoff", "exchange", "ring",
+    # iterative refinement and quantized updates
+    "factor", "residual", "correct", "escalate", "quantize",
+    "dequantize",
+)
+#: prefix of every scope in HLO ``op_name`` metadata
+SCOPE_PREFIX = "dplasma."
 
 
 class PhaseLedger:
@@ -127,36 +159,46 @@ _NOOP = _NoopSink()
 _nest: List[float] = []
 
 
+def _named_scope(name: str):
+    """The named scope a span opens (the single point the tests
+    patch)."""
+    import jax
+    return jax.named_scope(SCOPE_PREFIX + name)
+
+
 @contextlib.contextmanager
-def span(name: str):
-    """Time one phase region. Yields a sink; values the region passes
-    to the sink are fenced at exit *only when profiling is active* —
-    otherwise the whole thing is a no-op (no fencing, no timing).
-    Nested spans attribute self-time only (child seconds are
-    subtracted from the enclosing span)."""
-    led = _active
-    if led is None:
-        yield _NOOP
-        return
-    sink = _Sink()
-    _nest.append(0.0)
-    t0 = time.perf_counter()
-    try:
-        yield sink
-    finally:
+def span(name: str, *, timed: bool = True):
+    """One phase region, under the named scope ``dplasma.<name>``.
+    Yields a sink; values the region passes to the sink are fenced at
+    exit *only when profiling is active* and the span is ``timed`` —
+    otherwise nothing is timed or fenced. Nested spans attribute
+    self-time only (child seconds are subtracted from the enclosing
+    span)."""
+    with _named_scope(name):
+        led = _active
+        if led is None or not timed:
+            yield _NOOP
+            return
+        sink = _Sink()
+        _nest.append(0.0)
+        t0 = time.perf_counter()
         try:
-            if sink.values:
-                _fence(sink.values)
+            yield sink
         finally:
-            # balance _nest even when the fence raises (a poisoned
-            # array's block_until_ready — the failure the driver
-            # degrades to a warning): a leaked entry would corrupt
-            # every later span's child-time subtraction process-wide
-            elapsed = time.perf_counter() - t0
-            child = _nest.pop()
-            if _nest:
-                _nest[-1] += elapsed
-            led.add(name, max(elapsed - child, 0.0), total=elapsed)
+            try:
+                if sink.values:
+                    _fence(sink.values)
+            finally:
+                # balance _nest even when the fence raises (a poisoned
+                # array's block_until_ready — the failure the driver
+                # degrades to a warning): a leaked entry would corrupt
+                # every later span's child-time subtraction
+                # process-wide
+                elapsed = time.perf_counter() - t0
+                child = _nest.pop()
+                if _nest:
+                    _nest[-1] += elapsed
+                led.add(name, max(elapsed - child, 0.0), total=elapsed)
 
 
 @contextlib.contextmanager

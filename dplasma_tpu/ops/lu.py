@@ -83,11 +83,13 @@ def ipiv_to_perm(ipiv):
 def laswp(A: TileMatrix, perm, inverse: bool = False) -> TileMatrix:
     """Apply a global row permutation (dplasma_zlaswp analog): one
     gather instead of the reference's sequential row swaps."""
-    if inverse:
-        inv = jnp.zeros_like(perm).at[perm].set(
-            jnp.arange(perm.shape[0], dtype=perm.dtype))
-        perm = inv
-    return A.like(A.data[perm, :])
+    from dplasma_tpu.observability import phases
+    with phases.span("laswp", timed=False):
+        if inverse:
+            inv = jnp.zeros_like(perm).at[perm].set(
+                jnp.arange(perm.shape[0], dtype=perm.dtype))
+            perm = inv
+        return A.like(A.data[perm, :])
 
 
 # -- no-pivoting LU ----------------------------------------------------
@@ -480,22 +482,29 @@ def getrf_ptgpanel(A: TileMatrix):
     candidate election, an ICI all_gather playoff, masked-psum pivot
     row exchange — the shard_map re-design of the reference's 1,076
     JDF lines. Single-process grids fall back to :func:`getrf_1d`
-    (same (LU, perm) contract either way)."""
-    m = pmesh.active()
-    if m is not None and A.desc.mb == A.desc.nb:
-        P = m.shape[pmesh.ROW_AXIS]
-        Q = m.shape[pmesh.COL_AXIS]
-        if P * Q > 1:
-            from dplasma_tpu.descriptors import Dist
-            from dplasma_tpu.parallel import cyclic
-            d = A.desc.dist
-            if (d.P, d.Q) != (P, Q):  # grid comes from the mesh; keep
-                d = Dist(P=P, Q=Q)    # dist's kp/kq only when it fits
-            C = cyclic.CyclicMatrix.from_tile(A, d)
-            F, perm = cyclic.getrf_cyclic(C)
-            full = F.to_tile().data[perm]
-            return TileMatrix(pmesh.constrain2d(full), A.desc), perm
-    return getrf_1d(A)
+    (same (LU, perm) contract either way). The layout changes around
+    the distributed factorization (to cyclic storage and back, and the
+    row gather by ``perm``) carry the ``redistribute`` scope."""
+    from dplasma_tpu.observability import phases
+    with phases.span("getrf", timed=False):
+        m = pmesh.active()
+        if m is not None and A.desc.mb == A.desc.nb:
+            P = m.shape[pmesh.ROW_AXIS]
+            Q = m.shape[pmesh.COL_AXIS]
+            if P * Q > 1:
+                from dplasma_tpu.descriptors import Dist
+                from dplasma_tpu.parallel import cyclic
+                d = A.desc.dist
+                if (d.P, d.Q) != (P, Q):  # grid comes from the mesh;
+                    d = Dist(P=P, Q=Q)    # keep dist's kp/kq if it fits
+                with phases.span("redistribute", timed=False):
+                    C = cyclic.CyclicMatrix.from_tile(A, d)
+                F, perm = cyclic.getrf_cyclic(C)
+                with phases.span("redistribute", timed=False):
+                    full = F.to_tile().data[perm]
+                    full = pmesh.constrain2d(full)
+                return TileMatrix(full, A.desc), perm
+        return getrf_1d(A)
 
 
 def trsmpl_ptgpanel(LU: TileMatrix, perm, B: TileMatrix) -> TileMatrix:
@@ -507,14 +516,17 @@ def trsmpl_ptgpanel(LU: TileMatrix, perm, B: TileMatrix) -> TileMatrix:
 def getrs(trans: str, LU: TileMatrix, perm, B: TileMatrix) -> TileMatrix:
     """Solve op(A) X = B from a pivoted factorization
     (dplasma_zgetrs)."""
+    from dplasma_tpu.observability import phases
     trans = trans.upper()
-    if trans == "N":
-        Y = trsmpl_ptgpanel(LU, perm, B)
-        return blas3.trsm(1.0, LU, Y, side="L", uplo="U", trans="N")
-    # op(A) = A^T/A^H: U^x L^x P x = b
-    Y = blas3.trsm(1.0, LU, B, side="L", uplo="U", trans=trans)
-    Z = blas3.trsm(1.0, LU, Y, side="L", uplo="L", trans=trans, diag="U")
-    return laswp(Z, perm, inverse=True)
+    with phases.span("solve", timed=False):
+        if trans == "N":
+            Y = trsmpl_ptgpanel(LU, perm, B)
+            return blas3.trsm(1.0, LU, Y, side="L", uplo="U", trans="N")
+        # op(A) = A^T/A^H: U^x L^x P x = b
+        Y = blas3.trsm(1.0, LU, B, side="L", uplo="U", trans=trans)
+        Z = blas3.trsm(1.0, LU, Y, side="L", uplo="L", trans=trans,
+                       diag="U")
+        return laswp(Z, perm, inverse=True)
 
 
 def gesv_1d(A: TileMatrix, B: TileMatrix):

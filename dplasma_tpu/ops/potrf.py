@@ -59,108 +59,110 @@ def potrf(A: TileMatrix, uplo: str = "L", *, diag_kernel=None,
     skinny products that each re-streamed the column through HBM.
     ``lookahead=0`` is the per-panel baseline (bit-identical op
     order)."""
-    from dplasma_tpu.ops._sweep import sweep_params
-    la, _ = sweep_params(lookahead)
-    dk = diag_kernel if diag_kernel is not None else k.potrf
-    assert A.desc.mb == A.desc.nb, "potrf needs square tiles"
-    assert A.desc.M == A.desc.N, "potrf needs a square matrix"
-    nt = A.desc.KT
-    mb = A.desc.mb
-    lower = uplo.upper() == "L"
-    X = A.pad_diag().data
-    if (diag_kernel is None and A.dtype == jnp.float64
-            and k._dd_active(A.dtype)):
-        # d-precision fast path: the limb-cached blocked factorization
-        # (kernels.dd.potrf_f64_blocked) replaces the whole sweep — one
-        # split per finished column, one Newton inverse per panel,
-        # f32+IR diagonal tiles (VERDICT r2 weak #1 restructure).
-        from dplasma_tpu.kernels import dd as _dd
-        full = _dd.potrf_f64_blocked(X, nb=mb, lower=lower)
-        return TileMatrix(pmesh.constrain2d(full), A.desc)
-    Mp = X.shape[0]
-
-    # cols[j]: finished block column j (lower: rows j*mb.., width mb;
-    # upper: the mirrored row block), diagonal tile at the top/left.
-    # Regions carry phase spans (observability.phases) — inert unless
-    # a --phase-profile attributed pass has a ledger active.
     from dplasma_tpu.observability import phases
-    cols = []
-    for kk in range(nt):
-        s = kk * mb
-        fresh_from = max(kk - la, 0) if la > 0 else 0
-        if lower:
-            col = X[s:, s:s + mb]
-            if fresh_from > 0:
-                # aggregated wide product of the older panels (one
-                # column stream instead of fresh_from skinny ones)
-                with phases.span("far_flush") as _f:
-                    W = jnp.concatenate(
-                        [cols[j][s - j * mb:]
-                         for j in range(fresh_from)], axis=1)
-                    B = jnp.concatenate(
-                        [cols[j][s - j * mb:s - j * mb + mb]
-                         for j in range(fresh_from)], axis=1)
-                    col = _f(col - _quant.update_dot(
-                        W, B, tb=True, conj_b=True))
-            if fresh_from < kk:
-                with phases.span("lookahead") as _f:
-                    for j in range(fresh_from, kk):
-                        Lj = cols[j]
-                        off = s - j * mb
-                        col = col - _quant.update_dot(
-                            Lj[off:, :], Lj[off:off + mb, :],
-                            tb=True, conj_b=True)
-                    _f(col)
-            with phases.span("panel") as _f:
-                lkk = dk(col[:mb], lower=True)
-                if s + mb < Mp:
-                    pan = k.trsm(lkk, col[mb:], side="R", lower=True,
-                                 trans="C")
-                    cols.append(_f(jnp.concatenate([lkk, pan], axis=0)))
-                else:
-                    cols.append(_f(lkk))
-        else:
-            row = X[s:s + mb, s:]
-            if fresh_from > 0:
-                with phases.span("far_flush") as _f:
-                    W = jnp.concatenate(
-                        [cols[j][:, s - j * mb:]
-                         for j in range(fresh_from)], axis=0)
-                    B = jnp.concatenate(
-                        [cols[j][:, s - j * mb:s - j * mb + mb]
-                         for j in range(fresh_from)], axis=0)
-                    row = _f(row - _quant.update_dot(
-                        B, W, ta=True, conj_a=True))
-            if fresh_from < kk:
-                with phases.span("lookahead") as _f:
-                    for j in range(fresh_from, kk):
-                        Uj = cols[j]
-                        off = s - j * mb
-                        row = row - _quant.update_dot(
-                            Uj[:, off:off + mb], Uj[:, off:],
-                            ta=True, conj_a=True)
-                    _f(row)
-            with phases.span("panel") as _f:
-                ukk = dk(row[:, :mb], lower=False)
-                if s + mb < Mp:
-                    pan = k.trsm(ukk, row[:, mb:], side="L",
-                                 lower=False, trans="C")
-                    cols.append(_f(jnp.concatenate([ukk, pan], axis=1)))
-                else:
-                    cols.append(_f(ukk))
-    with phases.span("assemble") as _f:
-        if lower:
-            out = [jnp.concatenate(
-                [jnp.zeros((j * mb, mb), X.dtype), c], axis=0)
-                for j, c in enumerate(cols)]
-            full = jnp.concatenate(out, axis=1)
-        else:
-            out = [jnp.concatenate(
-                [jnp.zeros((mb, j * mb), X.dtype), c], axis=1)
-                for j, c in enumerate(cols)]
-            full = jnp.concatenate(out, axis=0)
-        _f(full)
-    return TileMatrix(pmesh.constrain2d(full), A.desc)
+    from dplasma_tpu.ops._sweep import sweep_params
+    with phases.span("potrf", timed=False):
+        la, _ = sweep_params(lookahead)
+        dk = diag_kernel if diag_kernel is not None else k.potrf
+        assert A.desc.mb == A.desc.nb, "potrf needs square tiles"
+        assert A.desc.M == A.desc.N, "potrf needs a square matrix"
+        nt = A.desc.KT
+        mb = A.desc.mb
+        lower = uplo.upper() == "L"
+        X = A.pad_diag().data
+        if (diag_kernel is None and A.dtype == jnp.float64
+                and k._dd_active(A.dtype)):
+            # d-precision fast path: the limb-cached blocked factorization
+            # (kernels.dd.potrf_f64_blocked) replaces the whole sweep — one
+            # split per finished column, one Newton inverse per panel,
+            # f32+IR diagonal tiles (VERDICT r2 weak #1 restructure).
+            from dplasma_tpu.kernels import dd as _dd
+            full = _dd.potrf_f64_blocked(X, nb=mb, lower=lower)
+            return TileMatrix(pmesh.constrain2d(full), A.desc)
+        Mp = X.shape[0]
+
+        # cols[j]: finished block column j (lower: rows j*mb.., width mb;
+        # upper: the mirrored row block), diagonal tile at the top/left.
+        # Regions carry phase spans (observability.phases): named scopes
+        # of the compiled program, timed only while a --phase-profile
+        # attributed pass has a ledger active.
+        cols = []
+        for kk in range(nt):
+            s = kk * mb
+            fresh_from = max(kk - la, 0) if la > 0 else 0
+            if lower:
+                col = X[s:, s:s + mb]
+                if fresh_from > 0:
+                    # aggregated wide product of the older panels (one
+                    # column stream instead of fresh_from skinny ones)
+                    with phases.span("far_flush") as _f:
+                        W = jnp.concatenate(
+                            [cols[j][s - j * mb:]
+                             for j in range(fresh_from)], axis=1)
+                        B = jnp.concatenate(
+                            [cols[j][s - j * mb:s - j * mb + mb]
+                             for j in range(fresh_from)], axis=1)
+                        col = _f(col - _quant.update_dot(
+                            W, B, tb=True, conj_b=True))
+                if fresh_from < kk:
+                    with phases.span("lookahead") as _f:
+                        for j in range(fresh_from, kk):
+                            Lj = cols[j]
+                            off = s - j * mb
+                            col = col - _quant.update_dot(
+                                Lj[off:, :], Lj[off:off + mb, :],
+                                tb=True, conj_b=True)
+                        _f(col)
+                with phases.span("panel") as _f:
+                    lkk = dk(col[:mb], lower=True)
+                    if s + mb < Mp:
+                        pan = k.trsm(lkk, col[mb:], side="R", lower=True,
+                                     trans="C")
+                        cols.append(_f(jnp.concatenate([lkk, pan], axis=0)))
+                    else:
+                        cols.append(_f(lkk))
+            else:
+                row = X[s:s + mb, s:]
+                if fresh_from > 0:
+                    with phases.span("far_flush") as _f:
+                        W = jnp.concatenate(
+                            [cols[j][:, s - j * mb:]
+                             for j in range(fresh_from)], axis=0)
+                        B = jnp.concatenate(
+                            [cols[j][:, s - j * mb:s - j * mb + mb]
+                             for j in range(fresh_from)], axis=0)
+                        row = _f(row - _quant.update_dot(
+                            B, W, ta=True, conj_a=True))
+                if fresh_from < kk:
+                    with phases.span("lookahead") as _f:
+                        for j in range(fresh_from, kk):
+                            Uj = cols[j]
+                            off = s - j * mb
+                            row = row - _quant.update_dot(
+                                Uj[:, off:off + mb], Uj[:, off:],
+                                ta=True, conj_a=True)
+                        _f(row)
+                with phases.span("panel") as _f:
+                    ukk = dk(row[:, :mb], lower=False)
+                    if s + mb < Mp:
+                        pan = k.trsm(ukk, row[:, mb:], side="L",
+                                     lower=False, trans="C")
+                        cols.append(_f(jnp.concatenate([ukk, pan], axis=1)))
+                    else:
+                        cols.append(_f(ukk))
+        with phases.span("assemble") as _f:
+            if lower:
+                out = [jnp.concatenate(
+                    [jnp.zeros((j * mb, mb), X.dtype), c], axis=0)
+                    for j, c in enumerate(cols)]
+                full = jnp.concatenate(out, axis=1)
+            else:
+                out = [jnp.concatenate(
+                    [jnp.zeros((mb, j * mb), X.dtype), c], axis=1)
+                    for j, c in enumerate(cols)]
+                full = jnp.concatenate(out, axis=0)
+            _f(full)
+        return TileMatrix(pmesh.constrain2d(full), A.desc)
 
 
 def potrf_rec(A: TileMatrix, uplo: str = "L",
@@ -352,11 +354,13 @@ def _lowmem_panel(col):
 def potrs(A: TileMatrix, B: TileMatrix, uplo: str = "L") -> TileMatrix:
     """Solve A X = B given the Cholesky factor (dplasma_zpotrs:
     two blocked TRSM sweeps)."""
-    if uplo.upper() == "L":
-        y = blas3.trsm(1.0, A, B, side="L", uplo="L", trans="N")
-        return blas3.trsm(1.0, A, y, side="L", uplo="L", trans="C")
-    y = blas3.trsm(1.0, A, B, side="L", uplo="U", trans="C")
-    return blas3.trsm(1.0, A, y, side="L", uplo="U", trans="N")
+    from dplasma_tpu.observability import phases
+    with phases.span("solve", timed=False):
+        if uplo.upper() == "L":
+            y = blas3.trsm(1.0, A, B, side="L", uplo="L", trans="N")
+            return blas3.trsm(1.0, A, y, side="L", uplo="L", trans="C")
+        y = blas3.trsm(1.0, A, B, side="L", uplo="U", trans="C")
+        return blas3.trsm(1.0, A, y, side="L", uplo="U", trans="N")
 
 
 def posv(A: TileMatrix, B: TileMatrix, uplo: str = "L"):

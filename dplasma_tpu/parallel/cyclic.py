@@ -515,6 +515,7 @@ def _getrf_cyclic_jit(data, desc: CyclicDesc, mesh,
 
     def body(local):
         from dplasma_tpu.kernels import blas as kb
+        from dplasma_tpu.observability.phases import span
         A = local.reshape(mloc, nloc)
         p = jax.lax.axis_index(pmesh.ROW_AXIS)
         q = jax.lax.axis_index(pmesh.COL_AXIS)
@@ -526,108 +527,127 @@ def _getrf_cyclic_jit(data, desc: CyclicDesc, mesh,
         for k in range(KT):
             qk = layout.owner(k, Q, d.kq, d.jq)
             lck = layout.local_index(k, Q, d.kq)
-            # 1) panel broadcast along 'q' — or the lookahead-carried
-            # pre-updated next column from the previous step
-            cs = jax.lax.dynamic_slice_in_dim(A, lck * mb, mb, axis=1)
-            if pan_next is None:
-                pan = _bcast_q(cs, q, qk, Q, ring, P, rchunks)
-            else:
-                pan = pan_next
-            panm = jnp.where(active[:, None], pan, 0)
-            # 2) local candidate election (one local LU per row-rank,
-            #    concurrently across 'p' — the distributed panel).
-            #    The panel engine selects the election/playoff kernel:
-            #    rec = the blocked-recursive fused panel (kernels.
-            #    panels, no vendor custom call), chain = lax.linalg.lu
-            #    (bit-identical pre-engine route). Local work only —
-            #    the collective schedule is IDENTICAL either way
-            #    (spmdcheck's exact-count contract holds per kernel).
-            if panel == "rec":
-                from dplasma_tpu.kernels import panels as _panels
-                _, cperm = _panels.lu_panel_rec(panm)
-            else:
-                _, _, cperm = jax.lax.linalg.lu(panm)
-            cand_pos = cperm[:mb]                          # (mb,) local
-            cands = panm[cand_pos]
-            # 3) playoff: all_gather candidates along 'p', replicated LU
-            allc = jax.lax.all_gather(cands, pmesh.ROW_AXIS)
-            allid = jax.lax.all_gather(gid[cand_pos], pmesh.ROW_AXIS)
-            if panel == "rec":
-                lu2, perm2 = _panels.lu_panel_rec(
-                    allc.reshape(P * mb, mb))
-            else:
-                lu2, _, perm2 = jax.lax.linalg.lu(
-                    allc.reshape(P * mb, mb))
-            wr = perm2[:mb]                                # stack index
-            win_gids = allid.reshape(P * mb)[wr]
-            top = lu2[:mb]                       # packed L11\U11 rows
-            wins.append(win_gids)
-            # 4) my winners -> local rows; retire them from the active set
-            mine = (wr // mb) == p
-            win_lrow = jnp.where(mine, cand_pos[wr % mb], mloc)
-            elim = jnp.zeros((mloc + 1,), bool).at[win_lrow].set(
-                True, mode="drop")[:mloc]
-            # 5) winner rows' current values for MY columns (masked psum
-            #    along 'p' — the pivot-row exchange)
-            sel = jnp.where(mine[:, None],
-                            A[jnp.where(mine, win_lrow, 0)], 0)
-            if ring and P > 1:
-                # winner rows ride the explicit 'p' ring: P-1
-                # shift-and-add hops (kernels.pallas_ring). Winner
-                # rows have exactly one owner, so the contributions
-                # are disjoint and the sum is bit-identical to psum's.
-                from dplasma_tpu.kernels import pallas_ring as _pring
-                wrows = _pring.ring_allreduce(
-                    sel, axis=pmesh.ROW_AXIS,
-                    axes=((pmesh.ROW_AXIS, P), (pmesh.COL_AXIS, Q)))
-            else:
-                wrows = jax.lax.psum(sel, pmesh.ROW_AXIS)  # (mb, nloc)
-            u12 = kb.trsm(top, wrows, side="L", lower=True, unit=True)
-            trailing = (gcol > k)[None, :]
-            u12 = jnp.where(trailing, u12, 0)
-            # 6) local L column + Schur update of my trailing columns
-            l21 = kb.trsm(jnp.triu(top), panm, side="R", lower=False)
-            l21 = jnp.where((active & ~elim)[:, None], l21, 0)
-            # 6b) lookahead: assemble the NEXT panel column — narrow
-            # Schur update + the winner-row substitution of step 8,
-            # broadcast along 'q' — BEFORE the wide local update, so
-            # step k+1's candidate election and playoff collectives
-            # overlap this step's MXU-bound Schur matmul
-            if lookahead > 0 and k + 1 < KT:
-                qk1 = layout.owner(k + 1, Q, d.kq, d.jq)
-                lck1 = layout.local_index(k + 1, Q, d.kq)
-                cs1 = jax.lax.dynamic_slice_in_dim(A, lck1 * mb, mb,
-                                                   axis=1)
-                u12k1 = jax.lax.dynamic_slice_in_dim(u12, lck1 * mb,
-                                                     mb, axis=1)
-                coln = cs1 - kb.dot(l21, u12k1)
-                coln = coln.at[win_lrow].set(
-                    jnp.where(mine[:, None], u12k1,
-                              coln[jnp.where(mine, win_lrow, 0)]),
+            with span("panel", timed=False):
+                # 1) panel broadcast along 'q' — or the lookahead-carried
+                # pre-updated next column from the previous step
+                cs = jax.lax.dynamic_slice_in_dim(A, lck * mb, mb, axis=1)
+                if pan_next is None:
+                    with span("bcast", timed=False):
+                        pan = _bcast_q(cs, q, qk, Q, ring, P, rchunks)
+                else:
+                    pan = pan_next
+                panm = jnp.where(active[:, None], pan, 0)
+                # 2) local candidate election (one local LU per
+                #    row-rank, concurrently across 'p' — the distributed
+                #    panel). The panel engine selects the
+                #    election/playoff kernel: rec = the
+                #    blocked-recursive fused panel (kernels.panels, no
+                #    vendor custom call), chain = lax.linalg.lu
+                #    (bit-identical pre-engine route). Local work only —
+                #    the collective schedule is IDENTICAL either way
+                #    (spmdcheck's exact-count contract holds per kernel).
+                with span("elect", timed=False):
+                    if panel == "rec":
+                        from dplasma_tpu.kernels import panels as _panels
+                        _, cperm = _panels.lu_panel_rec(panm)
+                    else:
+                        _, _, cperm = jax.lax.linalg.lu(panm)
+                    cand_pos = cperm[:mb]                  # (mb,) local
+                    cands = panm[cand_pos]
+                # 3) playoff: all_gather candidates along 'p',
+                # replicated LU
+                with span("playoff", timed=False):
+                    allc = jax.lax.all_gather(cands, pmesh.ROW_AXIS)
+                    allid = jax.lax.all_gather(gid[cand_pos],
+                                               pmesh.ROW_AXIS)
+                    if panel == "rec":
+                        lu2, perm2 = _panels.lu_panel_rec(
+                            allc.reshape(P * mb, mb))
+                    else:
+                        lu2, _, perm2 = jax.lax.linalg.lu(
+                            allc.reshape(P * mb, mb))
+                    wr = perm2[:mb]                        # stack index
+                    win_gids = allid.reshape(P * mb)[wr]
+                    top = lu2[:mb]               # packed L11\U11 rows
+                wins.append(win_gids)
+                # 4) my winners -> local rows; retire them from the
+                # active set
+                mine = (wr // mb) == p
+                win_lrow = jnp.where(mine, cand_pos[wr % mb], mloc)
+                elim = jnp.zeros((mloc + 1,), bool).at[win_lrow].set(
+                    True, mode="drop")[:mloc]
+                # 5) winner rows' current values for MY columns (masked
+                #    psum along 'p' — the pivot-row exchange)
+                with span("exchange", timed=False):
+                    sel = jnp.where(mine[:, None],
+                                    A[jnp.where(mine, win_lrow, 0)], 0)
+                    if ring and P > 1:
+                        # winner rows ride the explicit 'p' ring: P-1
+                        # shift-and-add hops (kernels.pallas_ring).
+                        # Winner rows have exactly one owner, so the
+                        # contributions are disjoint and the sum is
+                        # bit-identical to psum's.
+                        from dplasma_tpu.kernels import pallas_ring \
+                            as _pring
+                        wrows = _pring.ring_allreduce(
+                            sel, axis=pmesh.ROW_AXIS,
+                            axes=((pmesh.ROW_AXIS, P),
+                                  (pmesh.COL_AXIS, Q)))
+                    else:
+                        wrows = jax.lax.psum(sel, pmesh.ROW_AXIS)
+                u12 = kb.trsm(top, wrows, side="L", lower=True, unit=True)
+                trailing = (gcol > k)[None, :]
+                u12 = jnp.where(trailing, u12, 0)
+            with span("update", timed=False):
+                # 6) local L column + Schur update of my trailing
+                # columns
+                l21 = kb.trsm(jnp.triu(top), panm, side="R", lower=False)
+                l21 = jnp.where((active & ~elim)[:, None], l21, 0)
+                # 6b) lookahead: assemble the NEXT panel column —
+                # narrow Schur update + the winner-row substitution of
+                # step 8, broadcast along 'q' — BEFORE the wide local
+                # update, so step k+1's candidate election and playoff
+                # collectives overlap this step's MXU-bound Schur matmul
+                if lookahead > 0 and k + 1 < KT:
+                    with span("lookahead", timed=False):
+                        qk1 = layout.owner(k + 1, Q, d.kq, d.jq)
+                        lck1 = layout.local_index(k + 1, Q, d.kq)
+                        cs1 = jax.lax.dynamic_slice_in_dim(
+                            A, lck1 * mb, mb, axis=1)
+                        u12k1 = jax.lax.dynamic_slice_in_dim(
+                            u12, lck1 * mb, mb, axis=1)
+                        coln = cs1 - kb.dot(l21, u12k1)
+                        coln = coln.at[win_lrow].set(
+                            jnp.where(mine[:, None], u12k1,
+                                      coln[jnp.where(mine, win_lrow, 0)]),
+                            mode="drop")
+                        # ring: step k+1's panel transfer starts HERE,
+                        # before the wide Schur matmul below (the
+                        # overlap window)
+                        with span("bcast", timed=False):
+                            pan_next = _bcast_q(coln, q, qk1, Q, ring, P,
+                                                rchunks)
+                else:
+                    pan_next = None
+                A = A - kb.dot(l21, u12)
+                # 7) owners write the L column into the panel block
+                newcs = jnp.where((active & ~elim)[:, None], l21, cs)
+                A = jnp.where(q == qk,
+                              jax.lax.dynamic_update_slice_in_dim(
+                                  A, newcs, lck * mb, axis=1), A)
+                # 8) winner rows take their factor content (U12 on
+                #    trailing columns, packed L11\U11 in the panel block)
+                row_new = jnp.where(trailing, u12, wrows)
+                pancols = jnp.zeros((nloc,), bool).at[
+                    lck * mb + jnp.arange(mb)].set(q == qk)
+                paste = jnp.zeros((mb, nloc), A.dtype)
+                paste = jax.lax.dynamic_update_slice_in_dim(
+                    paste, top, lck * mb, axis=1)
+                row_new = jnp.where(pancols[None, :], paste, row_new)
+                A = A.at[win_lrow].set(
+                    jnp.where(mine[:, None], row_new,
+                              A[jnp.where(mine, win_lrow, 0)]),
                     mode="drop")
-                # ring: step k+1's panel transfer starts HERE, before
-                # the wide Schur matmul below (the overlap window)
-                pan_next = _bcast_q(coln, q, qk1, Q, ring, P, rchunks)
-            else:
-                pan_next = None
-            A = A - kb.dot(l21, u12)
-            # 7) owners write the L column into the panel block
-            newcs = jnp.where((active & ~elim)[:, None], l21, cs)
-            A = jnp.where(q == qk,
-                          jax.lax.dynamic_update_slice_in_dim(
-                              A, newcs, lck * mb, axis=1), A)
-            # 8) winner rows take their factor content (U12 on trailing
-            #    columns, packed L11\U11 in the panel block)
-            row_new = jnp.where(trailing, u12, wrows)
-            pancols = jnp.zeros((nloc,), bool).at[
-                lck * mb + jnp.arange(mb)].set(q == qk)
-            paste = jnp.zeros((mb, nloc), A.dtype)
-            paste = jax.lax.dynamic_update_slice_in_dim(
-                paste, top, lck * mb, axis=1)
-            row_new = jnp.where(pancols[None, :], paste, row_new)
-            A = A.at[win_lrow].set(jnp.where(mine[:, None], row_new,
-                                             A[jnp.where(mine, win_lrow, 0)]),
-                                   mode="drop")
             active = active & ~elim
         winsA = jnp.stack(wins)                            # (KT, mb)
         return (A.reshape(1, 1, mloc, nloc),

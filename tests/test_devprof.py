@@ -9,7 +9,9 @@ named diagnostic, the driver ``--devprof`` end-to-end path on
 dpotrf/dgetrf/dgeqrf, and the perfdiff extraction + ``--json``
 verdict round-trip over devprof metrics.
 """
+import gzip
 import json
+import pathlib
 import sys
 
 import pytest
@@ -317,3 +319,82 @@ def test_capture_synthetic_on_cpu():
         pass
     assert cap.used == "synthetic"
     assert cap.events == []
+
+
+# ---------------------------------------------- the jax backend's reader
+
+FIXTURE = pathlib.Path(__file__).resolve().parent.parent / "benchmark" / \
+    "tests" / "data" / "cholesky_f32_16384.xplane.pb.gz"
+
+
+def _capture_dir(tmp_path, fixture=FIXTURE):
+    """A profiler log directory holding the fixture as jax writes it."""
+    d = tmp_path / "plugins" / "profile" / "2026_01_01_00_00_00"
+    d.mkdir(parents=True)
+    (d / "host.xplane.pb").write_bytes(gzip.open(fixture).read())
+    return str(tmp_path)
+
+
+def test_jax_timeline_reads_the_chips_xplane(tmp_path):
+    """A capture from a TPU v5 lite (one posv call at 16384, f32):
+    every XLA op of the device plane becomes a timeline op on rank 0,
+    named by its HLO instruction, binned by the shared vocabulary."""
+    ops = dp._jax_timeline(_capture_dir(tmp_path))
+    assert len(ops) == 4416
+    assert {o["rank"] for o in ops} == {0}
+    assert ops[0]["name"] == "copy.2376"
+    assert ops[0]["category"] == "host"
+    assert ops[2]["name"] == "custom-call.309"
+    assert ops[2]["end_ns"] - ops[2]["begin_ns"] == 13967
+    assert all(o["end_ns"] >= o["begin_ns"] for o in ops)
+    cats = {}
+    for o in ops:
+        cats[o["category"]] = cats.get(o["category"], 0) + 1
+    assert cats == {"compute": 4226, "host": 190}
+    # the fixture's program predates the library's named scopes
+    assert {o["scope"] for o in ops} == {()}
+    entry = dp.ingest(ops, 0.0824, 1, backend="jax")
+    assert entry["backend"] == "jax" and entry["timeline_ops"] == 4416
+
+
+def test_xplane_op_names_and_scopes():
+    """The op name of each device op comes from its event metadata's
+    ``tf_op`` stat; its ``dplasma.*`` components are its scopes."""
+    names = dp.xplane_op_names(gzip.open(FIXTURE).read())
+    assert list(names) == ["/device:TPU:0"]
+    ops = names["/device:TPU:0"]
+    assert len(ops) == 2432
+    assert sum(1 for v in ops.values()
+               if v.startswith("jit(solve)/cholesky")) > 0
+    assert dp.op_scopes("jit(solve)/dplasma.potrf/dplasma.panel/"
+                        "cholesky:") == ("potrf", "panel")
+    assert dp.op_scopes("jit(solve)/jit(_jit_trail)/dplasma.update/"
+                        "dplasma.recombine[x]/pallas_call") == (
+        "update", "recombine")
+    assert dp.op_scopes("jit(solve)/cholesky:") == ()
+
+
+def test_capture_without_device_ops_says_so(tmp_path):
+    """A jax capture that holds no device ops (the CPU has no TPU
+    plane) falls back to the synthetic timeline, and says why."""
+    import jax.numpy as jnp
+    with dp.DevprofCapture(backend="jax", logdir=str(tmp_path)) as cap:
+        jnp.ones(8).block_until_ready()
+    assert cap.events == []
+    assert cap.used == "synthetic"
+    assert "no device ops" in cap.note
+
+
+def test_jax_timeline_carries_named_scopes(tmp_path):
+    """A capture of one posv call at 12288 (f32, TPU v5 lite) from a
+    tree whose spans open named scopes: each op carries the
+    ``dplasma.*`` scopes of its own op name."""
+    fixture = FIXTURE.parent / "cholesky_f32_12288_scoped.xplane.pb.gz"
+    ops = dp._jax_timeline(_capture_dir(tmp_path, fixture))
+    counts = {}
+    for o in ops:
+        counts[o["scope"]] = counts.get(o["scope"], 0) + 1
+    assert counts == {("solve",): 1280, (): 814, ("potrf", "panel"): 812,
+                      ("potrf", "far_flush"): 25,
+                      ("potrf", "assemble"): 23,
+                      ("potrf", "lookahead"): 11}

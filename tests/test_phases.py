@@ -2,7 +2,9 @@
 (observability.phases), the roofline efficiency ledger
 (observability.roofline), the driver's --phase-profile/--peaks-file
 acceptance path, and the tools/perfdiff.py regression gate."""
+import contextlib
 import json
+import re
 
 import jax.numpy as jnp
 import pytest
@@ -612,3 +614,195 @@ def test_cyclic_wrappers_emit_ring_span(devices8):
     rows = {r["phase"]: r for r in led.summary()}
     assert "ring" in rows and rows["ring"]["count"] == 1
     assert rows["ring"]["measured_s"] > 0
+
+
+# ---------------------------------------------- named scopes (compiled)
+
+#: the three programs the chip benchmark times, at a small size:
+#: program -> (dtype, MCA overrides, grid or None)
+SCOPED = {"posv_f32": ("float32", {}, None),
+          "posv_dd": ("float64", {"dd_gemm": "always"}, None),
+          "getrf_getrs_2x2": ("float32", {}, (2, 2))}
+#: the scopes each program's trace opens (grid: the ICI ring is off on
+#: the CPU, so its ``ring`` span never runs inside the program)
+SCOPES_OPENED = {
+    "posv_f32": {"potrf", "panel", "lookahead", "far_flush", "assemble",
+                 "solve"},
+    "posv_dd": {"potrf", "update", "panel", "split", "recombine",
+                "assemble", "solve"},
+    "getrf_getrs_2x2": {"getrf", "redistribute", "panel", "bcast",
+                        "elect", "playoff", "exchange", "update",
+                        "lookahead", "solve", "laswp"},
+}
+#: the --phase-profile ledger of one eager call, as it was before the
+#: spans opened named scopes (scope-only spans are never timed)
+LEDGER_COUNTS = {
+    "posv_f32": {"panel": 4, "far_flush": 2, "assemble": 1,
+                 "lookahead": 3},
+    "posv_dd": {},
+    "getrf_getrs_2x2": {"ring": 1},
+}
+_SCOPE_N, _SCOPE_NB = 128, 32
+
+
+@contextlib.contextmanager
+def _scoped_program(name, devices):
+    """(fn, args) of one program inside its MCA and grid context."""
+    from tests.conftest import mca_overrides
+    from dplasma_tpu.ops import potrf as potrf_mod
+    from dplasma_tpu.parallel import mesh as pmesh
+    dtype, mca, grid = SCOPED[name]
+    n, nb = _SCOPE_N, _SCOPE_NB
+    B = generators.plrnt(n, 1, nb, 1, seed=4, dtype=dtype)
+    if grid is None:
+        A = generators.plghe(float(n), n, nb, seed=3, dtype=dtype)
+
+        def fn(a, b):
+            return potrf_mod.posv(A.like(a), B.like(b))[1].data
+    else:
+        A = generators.plrnt(n, n, nb, nb, seed=3, dtype=dtype)
+
+        def fn(a, b):
+            F, perm = lu_mod.getrf_ptgpanel(A.like(a))
+            return lu_mod.getrs("N", F, perm, B.like(b)).data
+    grid_cm = (pmesh.use_grid(pmesh.make_mesh(*grid, devices))
+               if grid else contextlib.nullcontext())
+    with mca_overrides(mca), grid_cm:
+        yield fn, (A.data, B.data)
+
+
+def _compiled_text(fn, args) -> str:
+    """Compiled HLO text of a fresh trace: JAX's in-memory caches are
+    cleared (an inner jit's cached jaxpr keeps the scopes of its first
+    trace), and the persistent cache keys on metadata, so a program
+    that differs only in its scopes is compiled, not loaded."""
+    import jax
+    jax.clear_caches()
+    prev = jax.config.jax_compilation_cache_include_metadata_in_key
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      True)
+    try:
+        return jax.jit(fn).lower(*args).compile().as_text()
+    finally:
+        jax.config.update(
+            "jax_compilation_cache_include_metadata_in_key", prev)
+        jax.clear_caches()
+
+
+def _strip_metadata(text: str) -> str:
+    """The HLO text without its debug information: each instruction's
+    ``metadata={...}`` and the source tables (file names, functions,
+    locations, stack frames) the metadata points into."""
+    text = re.sub(r",? metadata=\{[^}]*\}", "", text)
+    return "\n".join(line for line in text.splitlines()
+                     if not re.match(r'\d+ [{"]', line))
+
+
+@pytest.fixture(scope="module")
+def scoped_hlo(devices8):
+    """{program: (scopes opened while tracing, HLO with scopes, HLO
+    with phases' named scope replaced by a null context)}."""
+    out = {}
+    real = phases._named_scope
+    for name in SCOPED:
+        opened = set()
+
+        def recording(scope):
+            opened.add(scope)
+            return real(scope)
+
+        with _scoped_program(name, devices8) as (fn, args):
+            phases._named_scope = recording
+            try:
+                scoped = _compiled_text(fn, args)
+                phases._named_scope = (
+                    lambda scope: contextlib.nullcontext())
+                plain = _compiled_text(fn, args)
+            finally:
+                phases._named_scope = real
+        out[name] = (opened, scoped, plain)
+    return out
+
+
+@pytest.mark.parametrize("prog", sorted(SCOPED))
+def test_span_scopes_reach_compiled_op_names(prog, scoped_hlo):
+    """Every span the program's trace opens names its ``dplasma.<name>``
+    scope, and each appears in the compiled HLO's op_name metadata."""
+    opened, scoped, _ = scoped_hlo[prog]
+    assert opened == SCOPES_OPENED[prog]
+    assert opened <= set(phases.SCOPES)
+    op_names = " ".join(re.findall(r'op_name="([^"]*)"', scoped))
+    for name in opened:
+        assert f"{phases.SCOPE_PREFIX}{name}/" in op_names, name
+
+
+@pytest.mark.parametrize("prog", sorted(SCOPED))
+def test_scopes_leave_compiled_hlo_unchanged(prog, scoped_hlo):
+    """The scopes are metadata only: with the metadata stripped, the
+    compiled program is the same instruction for instruction."""
+    _, scoped, plain = scoped_hlo[prog]
+    assert phases.SCOPE_PREFIX in scoped
+    assert phases.SCOPE_PREFIX not in plain
+    assert _strip_metadata(scoped) == _strip_metadata(plain)
+
+
+@pytest.mark.parametrize("prog", sorted(SCOPED))
+def test_phase_profile_ledger_counts_unchanged(prog, devices8,
+                                               monkeypatch):
+    """Under --phase-profile (an eager call with a ledger active) the
+    ledger counts what it counted before the spans opened scopes: the
+    scope-only spans are neither timed nor fenced."""
+    import jax
+    monkeypatch.setattr(phases, "_fence", jax.block_until_ready)
+    with _scoped_program(prog, devices8) as (fn, args):
+        with phases.profiling() as led:
+            jax.block_until_ready(fn(*args))
+    assert {r["phase"]: r["count"] for r in led.summary()} == \
+        LEDGER_COUNTS[prog]
+
+
+def _span_names(source: str, filename: str = "<src>"):
+    """(name or None, line) of every ``phases.span(...)`` call in a
+    module's source; None where the name is not a string literal."""
+    import ast
+    out = []
+    for node in ast.walk(ast.parse(source, filename)):
+        if not isinstance(node, ast.Call):
+            continue
+        f = node.func
+        direct = isinstance(f, ast.Name) and f.id == "span"
+        attr = (isinstance(f, ast.Attribute) and f.attr == "span"
+                and isinstance(f.value, ast.Name)
+                and f.value.id in ("phases", "_phases"))
+        if not (direct or attr):
+            continue
+        arg = node.args[0] if node.args else None
+        lit = (arg.value if isinstance(arg, ast.Constant)
+               and isinstance(arg.value, str) else None)
+        out.append((lit, node.lineno))
+    return out
+
+
+def test_span_names_are_in_the_scope_vocabulary():
+    """Every span the package opens names a literal in
+    ``phases.SCOPES`` (one vocabulary for the ledger, the compiled
+    programs' scopes and the benchmark's readers); an unknown or
+    computed name fails here."""
+    import pathlib
+    pkg = pathlib.Path(phases.__file__).resolve().parent.parent
+    bad, seen = [], set()
+    for path in sorted(pkg.rglob("*.py")):
+        src = path.read_text()
+        if "span(" not in src:
+            continue
+        for name, line in _span_names(src, str(path)):
+            seen.add(name)
+            if name not in phases.SCOPES:
+                bad.append(f"{path.relative_to(pkg)}:{line} {name!r}")
+    assert not bad, bad
+    assert {"potrf", "panel", "recombine", "redistribute"} <= seen
+    assert len(set(phases.SCOPES)) == len(phases.SCOPES)
+    # the sweep itself catches an unknown and a computed name
+    probe = ("with phases.span('pannel'):\n    pass\n"
+             "with span(f'x{k}', timed=False):\n    pass\n")
+    assert [n for n, _ in _span_names(probe)] == ["pannel", None]
