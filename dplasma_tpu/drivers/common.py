@@ -548,6 +548,8 @@ class Driver:
         self.prof.save_info("driver", name)
         self.prof.save_info("prec", getattr(ip, "prec", "d"))
         self.report = RunReport(name, ip)
+        from dplasma_tpu.ops import lu as _lu
+        self._getrs_routes0 = dict(_lu.GETRS_ROUTES)
         # --telemetry: the live instruments — streaming Prometheus
         # exporter over the run's metrics registry + a flight recorder
         # of structured run events (v13 "telemetry" report section)
@@ -741,6 +743,13 @@ class Driver:
             _cfg.pop_overrides(frame)
         self._mca_frames = []
         ip = self.ip
+        from dplasma_tpu.ops import lu as _lu
+        for route, n in sorted(_lu.GETRS_ROUTES.items()):
+            ran = n - self._getrs_routes0.get(route, 0)
+            if ran:
+                self.report.metrics.counter("lu_getrs_route_total",
+                                            route=route).inc(ran)
+        self._getrs_routes0 = dict(_lu.GETRS_ROUTES)
         if getattr(self, "telemetry", None) is not None:
             # final exporter flush + the v13 section, BEFORE the
             # report writes below so the document carries it

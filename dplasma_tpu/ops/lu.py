@@ -33,6 +33,8 @@ TPU-native design:
 """
 from __future__ import annotations
 
+import threading
+
 import jax.numpy as jnp
 from jax import lax
 
@@ -484,7 +486,11 @@ def getrf_ptgpanel(A: TileMatrix):
     JDF lines. Single-process grids fall back to :func:`getrf_1d`
     (same (LU, perm) contract either way). The layout changes around
     the distributed factorization (to cyclic storage and back, and the
-    row gather by ``perm``) carry the ``redistribute`` scope."""
+    row gather by ``perm``) carry the ``redistribute`` scope. On the
+    distributed branch the returned LU also carries the cyclic factor
+    and ``perm`` (``LU.cyclic_factor``), which :func:`getrs` solves on;
+    a program that never reads the dense factor then drops its
+    conversion back."""
     from dplasma_tpu.observability import phases
     with phases.span("getrf", timed=False):
         m = pmesh.active()
@@ -503,7 +509,12 @@ def getrf_ptgpanel(A: TileMatrix):
                 with phases.span("redistribute", timed=False):
                     full = F.to_tile().data[perm]
                     full = pmesh.constrain2d(full)
-                return TileMatrix(full, A.desc), perm
+                LU = TileMatrix(full, A.desc)
+                # a plain attribute, not a pytree field: it does not
+                # cross a jit boundary, so only a getrs traced with the
+                # factor (and with this perm) solves on the slabs
+                LU.cyclic_factor = (F, perm)
+                return LU, perm
         return getrf_1d(A)
 
 
@@ -513,12 +524,52 @@ def trsmpl_ptgpanel(LU: TileMatrix, perm, B: TileMatrix) -> TileMatrix:
     return blas3.trsm(1.0, LU, Bp, side="L", uplo="L", trans="N", diag="U")
 
 
+#: times :func:`getrs` was traced (or run eagerly) on each route:
+#: ``cyclic`` on the block-cyclic factor, ``dense`` on the natural-order
+#: one. The drivers export it as ``lu_getrs_route_total{route}``.
+GETRS_ROUTES = {"cyclic": 0, "dense": 0}
+_GETRS_ROUTES_LOCK = threading.Lock()
+
+
+def _count_route(route: str) -> None:
+    with _GETRS_ROUTES_LOCK:
+        GETRS_ROUTES[route] += 1
+
+
+def _cyclic_factor(trans: str, LU: TileMatrix, perm, B: TileMatrix):
+    """The block-cyclic factor to solve on, or None for the dense route:
+    only for trans N, a factor :func:`getrf_ptgpanel` returned in this
+    trace together with this very ``perm``, a right-hand side of its
+    dtype and row tiling, and an active mesh of the factor's grid."""
+    twin = getattr(LU, "cyclic_factor", None)
+    if trans != "N" or twin is None or twin[1] is not perm:
+        return None
+    F = twin[0]
+    m = pmesh.active()
+    d = F.desc.dist
+    if (m is None or B.dtype != F.dtype
+            or B.data.shape[0] != F.desc.MT * F.desc.mb
+            or (m.shape[pmesh.ROW_AXIS], m.shape[pmesh.COL_AXIS])
+            != (d.P, d.Q)):
+        return None
+    return F
+
+
 def getrs(trans: str, LU: TileMatrix, perm, B: TileMatrix) -> TileMatrix:
     """Solve op(A) X = B from a pivoted factorization
-    (dplasma_zgetrs)."""
+    (dplasma_zgetrs). A factor fresh from :func:`getrf_ptgpanel`'s
+    distributed branch is solved on its block-cyclic slabs
+    (:func:`~dplasma_tpu.parallel.cyclic.getrs_cyclic`, trans N, the
+    right-hand sides replicated); every other factor on the dense one."""
     from dplasma_tpu.observability import phases
     trans = trans.upper()
     with phases.span("solve", timed=False):
+        F = _cyclic_factor(trans, LU, perm, B)
+        _count_route("dense" if F is None else "cyclic")
+        if F is not None:
+            from dplasma_tpu.parallel import cyclic
+            Bz = B.zero_pad()
+            return Bz.like(cyclic.getrs_cyclic(F, perm, Bz.data)).zero_pad()
         if trans == "N":
             Y = trsmpl_ptgpanel(LU, perm, B)
             return blas3.trsm(1.0, LU, Y, side="L", uplo="U", trans="N")

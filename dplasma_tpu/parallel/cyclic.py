@@ -2046,66 +2046,119 @@ def potri_cyclic(L: CyclicMatrix) -> CyclicMatrix:
     return lauum_cyclic(trtri_cyclic(L))
 
 
-@partial(jax.jit, static_argnums=(2, 3))
-def _laswp_cyclic_jit(data, perm, desc, mesh):
-    """Row gather in slab space: out global row r = in global row
-    perm[r]. One all_gather along 'p' of the local column slab + a
-    cyclic index pick — per-rank transient is O(M * nloc), never the
-    natural-order global array (the pivot-application role of
-    src/zlaswp_wrapper.c on cyclic storage)."""
-    d = desc.dist
-    P = d.P
-    mb = desc.mb
-    mloc = desc.MTL * mb
-    nloc = desc.NTL * desc.nb
+@partial(jax.jit, static_argnums=(3, 4))
+def _getrs_cyclic_jit(data, perm, b, desc: CyclicDesc, mesh):
+    """Pivoted LU solve on :func:`getrf_cyclic`'s slabs, as they lie.
 
-    def body(loc, perm_):
-        A = loc.reshape(mloc, nloc)
+    The factor rows stay at their original places; ``perm`` gives each
+    local row its elimination position ``pos`` and step ``pos // mb``.
+    In column block k, the rows of step k hold the packed L11\\U11, the
+    rows of a later step hold L, those of an earlier step hold U. The
+    right-hand sides arrive replicated in natural row order and the
+    solution leaves replicated. Per step, forward then backward: the
+    mb right-hand-side entries of block k by masked psum along 'p',
+    the diagonal tile by masked psum over the grid (forward sweep
+    only; the backward sweep reuses it), one tile solve, and on the
+    ranks of column k the local product of the block column with the
+    solved block, psummed along 'q' into the rows still to solve. No
+    collective of the trace moves more than one mb x mb tile (the TPU
+    compiler merges the KT diagonal gathers, which wait on nothing of
+    the sweep, into one all-reduce)."""
+    from dplasma_tpu.kernels import blas as kb
+
+    d = desc.dist
+    P, Q = d.P, d.Q
+    mb = desc.mb
+    assert desc.mb == desc.nb and desc.M == desc.N, \
+        "getrs_cyclic needs a square factor with square tiles"
+    KT = desc.MT
+    Mp = KT * mb
+    mloc = desc.MTL * mb
+    nloc = desc.NTL * mb
+    grid = (pmesh.ROW_AXIS, pmesh.COL_AXIS)
+
+    def body(local, perm_, b_):
+        A = local.reshape(mloc, nloc)
         p = jax.lax.axis_index(pmesh.ROW_AXIS)
+        q = jax.lax.axis_index(pmesh.COL_AXIS)
         grow = _grow(desc.MTL, mb, p, P, d.kp, d.ip)
         gid = grow * mb + jnp.arange(mloc) % mb
-        allg = jax.lax.all_gather(A, pmesh.ROW_AXIS)
-        allg = allg.reshape(P * mloc, nloc)
-        pm = perm_.reshape(-1)
-        Mp = pm.shape[0]
-        src = pm[jnp.clip(gid, 0, Mp - 1)]           # global src row
-        t = src // mb
-        ps = (t // d.kp + d.ip) % P
-        ls = (t // (d.kp * P)) * d.kp + t % d.kp
-        idx = ps * mloc + ls * mb + src % mb
-        out = jnp.where((gid < Mp)[:, None], allg[idx], A)
-        return out.reshape(1, 1, mloc, nloc)
+        valid = gid < Mp
+        gsafe = jnp.where(valid, gid, 0)
+        inv = jnp.zeros((Mp,), jnp.int32).at[perm_].set(
+            jnp.arange(Mp, dtype=jnp.int32))
+        pos = inv[gsafe]
+        # over-allocated slots (no global row) take step KT: never
+        # gathered, never updated from a later block
+        step = jnp.where(valid, pos // mb, KT)
+        slot = pos % mb
+
+        def gather(x, k, axes):
+            # rows of step k, in elimination order, summed over ``axes``
+            tgt = jnp.where(step == k, slot, mb)
+            g = jnp.zeros((mb,) + x.shape[1:], x.dtype).at[tgt].set(
+                x, mode="drop")
+            return jax.lax.psum(g, axes)
+
+        def block_col(k):
+            qk = layout.owner(k, Q, d.kq, d.jq)
+            lck = layout.local_index(k, Q, d.kq)
+            return qk, jax.lax.dynamic_slice_in_dim(A, lck * mb, mb,
+                                                    axis=1)
+
+        def update(r, k, qk, cs, xk, rows):
+            prod = jnp.where((q == qk) & rows[:, None], kb.dot(cs, xk), 0)
+            return r - jax.lax.psum(prod, pmesh.COL_AXIS)
+
+        r = jnp.where(valid[:, None], b_[gsafe], 0)
+        ys, diag = [], []
+        for k in range(KT):                  # L y = b[perm]
+            qk, cs = block_col(k)
+            dk = gather(jnp.where(q == qk, cs, 0), k, grid)
+            yk = kb.trsm(dk, gather(r, k, pmesh.ROW_AXIS), side="L",
+                         lower=True, unit=True)
+            r = update(r, k, qk, cs, yk, step > k)
+            ys.append(yk)
+            diag.append(dk)
+        y = jnp.concatenate(ys, axis=0)
+        z = jnp.where(valid[:, None], y[jnp.where(valid, pos, 0)], 0)
+        xs = [None] * KT
+        for k in range(KT - 1, -1, -1):      # U x = y
+            qk, cs = block_col(k)
+            xk = kb.trsm(diag[k], gather(z, k, pmesh.ROW_AXIS),
+                         side="L", lower=False)
+            z = update(z, k, qk, cs, xk, step < k)
+            xs[k] = xk
+        return jnp.concatenate(xs, axis=0)
 
     f = shard_map(
         body, mesh=mesh,
         in_specs=(PartitionSpec(pmesh.ROW_AXIS, pmesh.COL_AXIS, None,
                                 None),
-                  PartitionSpec()),
-        out_specs=PartitionSpec(pmesh.ROW_AXIS, pmesh.COL_AXIS, None,
-                                None))
-    return f(data, perm)
+                  PartitionSpec(), PartitionSpec()),
+        out_specs=PartitionSpec(),
+        # x is replicated by construction: every rank solves the same
+        # blocks from psummed operands
+        check_vma=False)
+    return f(data, perm, b)
 
 
-def laswp_cyclic(A: CyclicMatrix, perm) -> CyclicMatrix:
-    """Apply a global row permutation to cyclic slabs (out row r = in
-    row perm[r])."""
-    m = _mesh_of(A)
-    return CyclicMatrix(
-        _laswp_cyclic_jit(A.data, jnp.asarray(perm), A.desc, m),
-        A.desc)
-
-
-def getrs_cyclic(LU: CyclicMatrix, perm, B: CyclicMatrix
-                 ) -> CyclicMatrix:
+def getrs_cyclic(LU: CyclicMatrix, perm, B):
     """Solve A X = B from :func:`getrf_cyclic`'s output without leaving
-    the slabs (pdgetrs): the factor rows live at their ORIGINAL
-    positions with elimination order in ``perm``, so one distributed
-    row gather puts both the factor and B in elimination order, then
-    unit-lower and upper TRSM sweeps run on slabs."""
-    Lp = laswp_cyclic(LU, perm)
-    Bp = laswp_cyclic(B, perm)
-    Y = trsm_cyclic(Lp, Bp, "N", unit=True)
-    return trsm_cyclic(Lp, Y, "N", uplo="U")
+    the slabs (pdgetrs): the factor rows stay where the factorization
+    left them, ``perm`` names their elimination order
+    (:func:`_getrs_cyclic_jit`). ``B`` is either a CyclicMatrix on the
+    factor's grid and row tiling (the answer comes back as one) or a
+    replicated natural-order (Mp, nrhs) array (the answer comes back
+    replicated, in the same shape)."""
+    m = _mesh_of(LU)
+    if not isinstance(B, CyclicMatrix):
+        return _getrs_cyclic_jit(LU.data, perm, B, LU.desc, m)
+    assert (LU.desc.dist == B.desc.dist and LU.desc.mb == B.desc.mb
+            and LU.desc.M == B.desc.M), "getrs_cyclic: mismatched descs"
+    Bt = B.to_tile()
+    x = _getrs_cyclic_jit(LU.data, perm, Bt.data, LU.desc, m)
+    return CyclicMatrix.from_tile(Bt.like(x), B.desc.dist)
 
 
 @partial(jax.jit, static_argnums=(1, 2))

@@ -373,6 +373,183 @@ def test_getrs_cyclic_solves_in_slabs(devices8):
     assert ok, r
 
 
+def _routes():
+    from dplasma_tpu.ops import lu as lu_mod
+    return dict(lu_mod.GETRS_ROUTES)
+
+
+def _routes_since(before):
+    return {k: v - before[k] for k, v in _routes().items()}
+
+
+@pytest.mark.parametrize("grid,kp,kq,nrhs,dtype", [
+    ((2, 2), 1, 1, 1, jnp.float32),
+    ((2, 2), 2, 2, 5, jnp.float64),
+    ((2, 2), 1, 2, 5, jnp.float32),
+    ((2, 4), 1, 2, 1, jnp.float64),
+    ((2, 4), 2, 1, 5, jnp.float32),
+    ((2, 4), 2, 2, 1, jnp.float64),
+])
+def test_getrf_getrs_one_program_on_slabs(devices8, grid, kp, kq, nrhs,
+                                          dtype):
+    """getrf_ptgpanel -> getrs("N") inside one jit solves on the cyclic
+    slabs (ragged N, every kp/kq in {1, 2}) and agrees with the dense
+    route on the same factor."""
+    from dplasma_tpu.ops import checks, lu as lu_mod
+    N, mb = 52, 8
+    dist = Dist(P=grid[0], Q=grid[1], kp=kp, kq=kq)
+    A = generators.plrnt(N, N, mb, mb, seed=21, dist=dist, dtype=dtype)
+    B = generators.plrnt(N, nrhs, mb, mb, seed=22, dtype=dtype)
+
+    def prog(a, b):
+        LU, perm = lu_mod.getrf_ptgpanel(A.like(a))
+        x = lu_mod.getrs("N", LU, perm, B.like(b))
+        # .like drops the cyclic factor: the dense route, same factor
+        xd = lu_mod.getrs("N", LU.like(LU.data), perm, B.like(b))
+        return x.data, xd.data
+
+    before = _routes()
+    with mesh.use_grid(mesh.make_mesh(*grid, devices8)):
+        x, xd = jax.jit(prog)(A.data, B.data)
+    assert _routes_since(before) == {"cyclic": 1, "dense": 1}
+    assert x.shape == B.data.shape and x.dtype == B.dtype
+    tol = 1e-4 if dtype == jnp.float32 else 1e-10
+    np.testing.assert_allclose(np.asarray(x), np.asarray(xd), rtol=tol,
+                               atol=tol)
+    r, ok = checks.check_axmb(A, B, TileMatrix(x, B.desc))
+    assert ok, r
+
+
+@pytest.mark.parametrize("case", ["jit_boundary", "trans_T", "trans_C",
+                                  "foreign_perm", "getrf_1d"])
+def test_getrs_falls_back_to_the_dense_route(devices8, case):
+    """Whatever getrs cannot see the slabs through solves on the dense
+    factor as before: a factor that crossed a jit boundary, trans T/C,
+    a perm other than the one returned with the factor, getrf_1d's
+    factor. Each is counted as ``dense`` and solves op(A) X = B."""
+    from dplasma_tpu.ops import lu as lu_mod
+    N, mb, nrhs = 52, 8, 3
+    A = generators.plrnt(N, N, mb, mb, seed=31, dtype=jnp.float64)
+    B = generators.plrnt(N, nrhs, mb, mb, seed=32, dtype=jnp.float64)
+    trans = case[-1] if case.startswith("trans") else "N"
+
+    def prog(a, b):
+        At = A.like(a)
+        LU, perm = (lu_mod.getrf_1d(At) if case == "getrf_1d"
+                    else lu_mod.getrf_ptgpanel(At))
+        if case == "foreign_perm":
+            perm = perm + 0
+        return lu_mod.getrs(trans, LU, perm, B.like(b)).data
+
+    before = _routes()
+    m = mesh.make_mesh(2, 2, devices8)
+    with mesh.use_grid(m):
+        if case == "jit_boundary":
+            # a fresh function: jit's cache does not key on the mesh
+            LU, perm = jax.jit(lambda a: lu_mod.getrf_ptgpanel(a))(A)
+            rep = jax.sharding.NamedSharding(m, jax.sharding.PartitionSpec())
+            perm, b = jax.device_put((perm, B.data), rep)
+            x = lu_mod.getrs("N", LU, perm, B.like(b)).data
+        else:
+            x = jax.jit(prog)(A.data, B.data)
+    assert _routes_since(before) == {"cyclic": 0, "dense": 1}
+    a = np.asarray(A.to_dense())
+    opa = a if trans == "N" else a.T
+    xs = np.asarray(x)[:N, :nrhs]
+    b = np.asarray(B.to_dense())
+    r = np.abs(opa @ xs - b).max() / (np.abs(a).max() * np.abs(xs).max()
+                                       * N * np.finfo(np.float64).eps)
+    assert r < 60, r
+
+
+def test_getrs_route_count_loses_no_update():
+    """Threads that trace getrs at once (serving's workers) all count."""
+    import sys
+    import threading
+    from dplasma_tpu.ops import lu as lu_mod
+    before = _routes()
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        ts = [threading.Thread(target=lambda: [
+            lu_mod._count_route("cyclic") for _ in range(2000)])
+            for _ in range(8)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in ts)
+    finally:
+        sys.setswitchinterval(old)
+    assert _routes_since(before) == {"cyclic": 16000, "dense": 0}
+
+
+@pytest.mark.parametrize("prog,grid,route", [
+    ("testing_sgetrf_1d", ["-p", "1", "-q", "1", "-x"], "dense"),
+    ("testing_dgesv_ir", ["-p", "2", "-q", "2"], "cyclic"),
+])
+def test_driver_report_counts_getrs_routes(tmp_path, capsys, prog, grid,
+                                           route):
+    """The run report exports lu_getrs_route_total{route}: getrf_1d's
+    check solves on the dense factor, the grid's IR solves on the
+    cyclic one."""
+    import json
+    from dplasma_tpu.drivers import main
+    rj = str(tmp_path / "r.json")
+    rc = main(["-N", "64", "-t", "16", *grid, f"--report={rj}"],
+              prog=prog)
+    capsys.readouterr()
+    assert rc == 0
+    doc = json.load(open(rj))
+    got = {m["labels"]["route"]: m["value"] for m in doc["metrics"]
+           if m["name"] == "lu_getrs_route_total"}
+    assert got.get(route, 0) >= 1, got
+    assert set(got) <= {"cyclic", "dense"}
+
+
+def test_getrs_on_slabs_drops_the_dense_factor(devices8):
+    """With MCA cyclic.convert=a2a (the accelerator route) on a 2x2
+    mesh, a factor -> solve program that returns only X keeps the way
+    into the slabs and drops the way back: half the redistribute
+    all_to_all ops, and no gather of the dense factor by perm. The same
+    program returning (F, perm, X) still builds the dense factor, equal
+    to the factorization's own."""
+    import re
+    from tests.conftest import mca_overrides
+    from dplasma_tpu.ops import lu as lu_mod
+    n, nb = 128, 32
+    A = generators.plrnt(n, n, nb, nb, seed=3, dtype=jnp.float32)
+    B = generators.plrnt(n, 1, nb, 1, seed=4, dtype=jnp.float32)
+
+    def solve(a, b):
+        LU, perm = lu_mod.getrf_ptgpanel(A.like(a))
+        return LU.data, perm, lu_mod.getrs("N", LU, perm, B.like(b)).data
+
+    def redistribute_ops(text, kind, scope="dplasma.redistribute/"):
+        return [ln for ln in text.splitlines()
+                if re.search(rf" {kind}(-start)?\(", ln) and scope in ln]
+
+    with mca_overrides({"cyclic.convert": "a2a"}), \
+            mesh.use_grid(mesh.make_mesh(2, 2, devices8)):
+        x_only = jax.jit(lambda a, b: solve(a, b)[2])
+        full = jax.jit(solve)
+        texts = [f.lower(A.data, B.data).compile().as_text()
+                 for f in (x_only, full)]
+        x = x_only(A.data, B.data)
+        F, perm, x_full = full(A.data, B.data)
+        F0, perm0 = jax.jit(lambda a: lu_mod.getrf_ptgpanel(a))(A)
+    a2a = [len(redistribute_ops(t, "all-to-all")) for t in texts]
+    assert a2a[1] == 4 and a2a[0] == a2a[1] // 2, a2a
+    # the row gather by perm (GSPMD makes it an all-gather on the CPU)
+    by_perm = [len(redistribute_ops(t, "(all-)?gather",
+                                    "dplasma.redistribute/gather"))
+               for t in texts]
+    assert by_perm[0] == 0 and by_perm[1] > 0, by_perm
+    np.testing.assert_array_equal(np.asarray(F), np.asarray(F0.data))
+    np.testing.assert_array_equal(np.asarray(perm), np.asarray(perm0))
+    np.testing.assert_array_equal(np.asarray(x), np.asarray(x_full))
+
+
 def test_herk_cyclic_rectangular(devices8):
     """C = A A^H for rectangular A: C follows the M x M descriptor,
     not A's column tiling (review r4)."""

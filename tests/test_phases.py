@@ -624,7 +624,8 @@ SCOPED = {"posv_f32": ("float32", {}, None),
           "posv_dd": ("float64", {"dd_gemm": "always"}, None),
           "getrf_getrs_2x2": ("float32", {}, (2, 2))}
 #: the scopes each program's trace opens (grid: the ICI ring is off on
-#: the CPU, so its ``ring`` span never runs inside the program)
+#: the CPU, so its ``ring`` span never runs inside the program; getrs
+#: solves on the cyclic slabs, so no ``laswp``)
 SCOPES_OPENED = {
     "posv_f32": {"potrf", "panel", "lookahead", "far_flush", "assemble",
                  "solve"},
@@ -632,7 +633,7 @@ SCOPES_OPENED = {
                 "assemble", "solve"},
     "getrf_getrs_2x2": {"getrf", "redistribute", "panel", "bcast",
                         "elect", "playoff", "exchange", "update",
-                        "lookahead", "solve", "laswp"},
+                        "lookahead", "solve"},
 }
 #: the --phase-profile ledger of one eager call, as it was before the
 #: spans opened named scopes (scope-only spans are never timed)

@@ -142,8 +142,9 @@ def test_every_cyclic_kernel_is_structurally_clean(devices8):
                           mesh=m), (data,)),
         ("band_extract", partial(cyclic._band_extract_cyclic_jit,
                                  desc=desc, mesh=m), (data,)),
-        ("laswp", partial(cyclic._laswp_cyclic_jit, desc=desc,
-                          mesh=m), (data, perm)),
+        ("getrs", partial(cyclic._getrs_cyclic_jit, desc=desc,
+                          mesh=m),
+         (data, perm, jnp.zeros((16, 2), jnp.float32))),
         ("identity", partial(cyclic._identity_cyclic_jit, desc=desc,
                              mesh=m), (data,)),
     ]
