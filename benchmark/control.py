@@ -27,7 +27,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def wrap_for(config: dict, stated: bool = False):
     """``harness.run``'s ``wrap``: ``reference.solve`` in the entry's
     place, in the control's precision (or, ``stated``, the
-    configuration's own), answering in the entry's dtype."""
+    configuration's own), answering in the entry's dtype. A service's
+    configuration names a solver per op (``{"posv": "cholesky", ...}``):
+    there the reference takes the place of the batched solve
+    ``fn(op, a, b)``, one problem of the batch at a time."""
+    import jax
+
     from benchmark import reference
     if stated:
         lower, precision = config["dtype"], "highest"
@@ -35,11 +40,21 @@ def wrap_for(config: dict, stated: bool = False):
         lower = config["control"]["dtype"]
         precision = config["control"]["precision"]
 
+    def one(solver, a, b):
+        return reference.solve(solver, a.astype(lower), b.astype(lower),
+                               config["nb"], precision).astype(a.dtype)
+
+    if isinstance(config["solver"], dict):
+        def wrap(_fn):
+            def solve(op, a, b):
+                return jax.vmap(lambda x, y: one(config["solver"][op], x,
+                                                 y))(a, b)
+            return solve
+        return wrap
+
     def wrap(_fn):
         def solve(a, b):
-            return reference.solve(config["solver"], a.astype(lower),
-                                   b.astype(lower), config["nb"],
-                                   precision).astype(a.dtype)
+            return one(config["solver"], a, b)
         return solve
     return wrap
 
@@ -67,6 +82,8 @@ def main(argv=None) -> int:
                     help="the reference at the configuration's precision")
     ns = ap.parse_args(argv)
     sys.path.insert(0, ROOT)
+    from benchmark.run import use_checkout_cache
+    use_checkout_cache()
     for seed in [int(s) for s in ns.seeds.split(",")]:
         line = run(ns.workload, seed, ns.seconds, stated=ns.stated)
         print(json.dumps(dict(line, seed=seed, stated=ns.stated)),

@@ -1,6 +1,9 @@
 """One run of one cell: set-up, the measured window, the check, the line.
 
-``run`` is what ``benchmark/run.py`` calls. Set-up makes the cell's
+``run`` is what ``benchmark/run.py`` calls. An entry that serves
+requests (``benchmark.ops.Service``) is run by ``benchmark/serve.py``;
+what follows is the run of an entry that is one compiled call
+(``benchmark.ops.Program``). Set-up makes the cell's
 matrix and right-hand sides on the device from the seed, compiles the
 one program the window calls (or loads it from the persistent cache),
 and runs it once. The window then drives that compiled program through
@@ -26,7 +29,7 @@ import time
 
 import numpy as np
 
-from benchmark import gen, hlo, reduce, reference, spec
+from benchmark import gen, hlo, ops, reduce, reference, serve, spec
 
 #: answers compared per run at most (a sample drawn from the seed)
 MAX_COMPARED = 256
@@ -77,7 +80,8 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
 
     c = spec.cell(spec.load_spec(root), cell_name, root)
     cfg = dict(c.config, **(sizes or {}))
-    cfg["nrhs"] = int(c.traffic["nrhs"])
+    if "nrhs" in c.traffic:
+        cfg["nrhs"] = int(c.traffic["nrhs"])
     if require_chip:
         _check_devices(jax, c.chips)
     devices = jax.devices()[:c.chips]
@@ -89,6 +93,13 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     t0 = time.perf_counter()
     prog = spec.entry(c).build(cfg, devices)
     parts["build"] = time.perf_counter() - t0
+    if isinstance(prog, ops.Service):
+        return serve.run(c, cfg, prog, seed, seconds, trace,
+                         t_process=t_process, parts=parts, devices=devices,
+                         wrap=wrap, trace_dir=trace_dir,
+                         max_compared=MAX_COMPARED,
+                         trace_seconds=TRACE_SECONDS,
+                         compile_counter=_compile_counter)
     fn = prog.fn if wrap is None else wrap(prog.fn)
     n, nrhs = cfg["N"], cfg["nrhs"]
     bump = float(n) if cfg.get("bump") == "N" else float(cfg.get("bump", 0))
@@ -164,7 +175,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
             line["device"]["busy_s"] = t.busy_s()
             line["device"]["window_s"] = t.window_s
             ctx = {"trace": t, "device_kind": dev0.device_kind,
-                   "cell": c, "config": cfg}
+                   "cell": c, "config": cfg, "hlo_texts": hlo_texts}
             for m in c.per_layer:
                 v = spec.reader(c, m["name"]).read(ctx)
                 if v is not None:

@@ -171,6 +171,13 @@ def _product_flops(ins: dict, shapes: dict):
     return 2.0 * _prod(out) * k, ltype
 
 
+def module_name(text: str) -> str:
+    """The module's name, from its first line (``HloModule jit_fn, ...``)."""
+    head = text.split("\n", 1)[0]
+    return head.split()[1].rstrip(",") if head.startswith("HloModule ") \
+        else ""
+
+
 def index(texts) -> dict:
     """{instruction name: info} over the modules in ``texts``."""
     out: dict = {}

@@ -40,11 +40,12 @@ def _op_name(event_name: str) -> str:
     return event_name.split(" = ", 1)[0].strip().lstrip("%")
 
 
-def load(path: str) -> dict:
+def load(path: str, programs: bool = False) -> dict:
     """{"devices": {plane: [(op name, t0_ns, t1_ns), ...]},
     "modules": {plane: [(t0_ns, t1_ns), ...]},
     "spans": [(name, t0_ns, t1_ns), ...]} of one xplane file (raw or
-    gzipped)."""
+    gzipped). With ``programs``, each module run also keeps its name,
+    ``(t0_ns, t1_ns, name)``, for ``name_by_program``."""
     from jax.profiler import ProfileData
     opener = gzip.open if path.endswith(".gz") else open
     with opener(path, "rb") as f:
@@ -61,6 +62,7 @@ def load(path: str) -> dict:
                 elif line.name == MODULES_LINE:
                     modules[plane.name] = sorted(
                         (ev.start_ns, ev.start_ns + ev.duration_ns)
+                        + ((ev.name,) if programs else ())
                         for ev in line.events)
         else:
             for line in plane.lines:
@@ -72,15 +74,45 @@ def load(path: str) -> dict:
             "modules": modules, "spans": spans}
 
 
-def clock_shift(raw: dict) -> float:
+def name_by_program(raw: dict, modules: dict) -> dict:
+    """``raw`` (loaded with ``programs``) with each device op renamed
+    ``<program>/<op>`` by the program whose run it lies in. ``modules``
+    is ``{program: HLO module name}`` (``hlo.module_name``); a run
+    belongs to a program when the run's name is that module's name and
+    its id, ``jit_fn(1234)``. An op in no run, or in a run whose module
+    name is no program's or more than one program's, keeps its bare
+    name."""
+    owner: dict = {}
+    for prog, mod in modules.items():
+        owner[mod] = None if mod in owner else prog
+    devices, out_modules = {}, {}
+    for plane, evs in raw["devices"].items():
+        runs = raw["modules"].get(plane, [])
+        starts = [m[0] for m in runs]
+        out = []
+        for n, a, b in evs:
+            i = bisect.bisect_right(starts, a) - 1
+            prog = None
+            if i >= 0 and a < runs[i][1]:
+                prog = owner.get(runs[i][2].rsplit("(", 1)[0])
+            out.append((f"{prog}/{n}" if prog else n, a, b))
+        devices[plane] = out
+    for plane, runs in raw["modules"].items():
+        out_modules[plane] = [(m[0], m[1]) for m in runs]
+    return dict(raw, devices=devices, modules=out_modules)
+
+
+def clock_shift(raw: dict, launch: str = "call") -> float:
     """Nanoseconds to add to device times so that no program starts on a
-    device before the host call that launched it.
+    device before the host span that launched it.
 
     Host and device events come from two clocks that the profiler
     aligns to about a millisecond. In a closed loop each program run
     follows its own ``call`` span, so where a run starts before the
-    nearest call span, that lead is the device clock's."""
-    calls = sorted(s[1] for s in raw["spans"] if s[0] == "call")
+    nearest call span, that lead is the device clock's. A server
+    launches its programs from spans of its own (``launch``); spans of
+    any other name launch nothing and set no shift."""
+    calls = sorted(s[1] for s in raw["spans"] if s[0] == launch)
     lead = 0.0
     for mods in raw["modules"].values():
         for t0, _ in mods:
@@ -130,7 +162,7 @@ class Trace:
     """One traced window: device operations joined to the HLO index,
     and the host spans, all cut to [t0, t1] (ns)."""
 
-    def __init__(self, raw: dict, hlo_index: dict):
+    def __init__(self, raw: dict, hlo_index: dict, launch: str = "call"):
         spans = raw["spans"]
         if not spans:
             raise ValueError("the trace holds none of the harness's spans")
@@ -141,7 +173,7 @@ class Trace:
         self.t1 = max(s[2] for s in spans)
         self.spans = [s for s in spans if s[2] > self.t0]
         self.hlo = hlo_index
-        self.shift_ns = clock_shift(raw)
+        self.shift_ns = clock_shift(raw, launch)
         self.devices = {}
         for plane, evs in raw["devices"].items():
             sh = self.shift_ns

@@ -33,14 +33,10 @@ import tempfile  # noqa: E402
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def main(argv=None) -> int:
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--workload", required=True)
-    ap.add_argument("--seed", type=int, required=True)
-    ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
-    ns = ap.parse_args(argv)
-
+def use_checkout_cache() -> None:
+    """JAX's persistent compilation cache at ``<checkout>/.jax_cache``,
+    keeping every program; the TPU runtime's logs under ``TMPDIR``.
+    Call before anything touches a device."""
     cache = os.path.join(ROOT, ".jax_cache")
     os.environ["JAX_COMPILATION_CACHE_DIR"] = cache
     # the TPU runtime's logs go under TMPDIR, not to its fixed /tmp path
@@ -52,6 +48,17 @@ def main(argv=None) -> int:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     jax.config.update("jax_compilation_cache_max_size", -1)
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ns = ap.parse_args(argv)
+
+    use_checkout_cache()
     from benchmark import harness
     try:
         line = harness.run(ns.workload, ns.seed, ns.seconds, bool(ns.trace),

@@ -135,6 +135,11 @@ PINNED = {
     "matmul_roofline_pct.short_calls": 15.261601321879265,
     "panel_pct": 14.852596258266557,
     "panel_pct.short_calls": 14.852596258266557,
+    # the request-serving cell's readers: a closed call has no requests
+    "device_idle_pct.open": 1.326226600718039,
+    "matmul_roofline_pct.open": 15.261601321879265,
+    "pad_flops_pct.open": None,
+    "queue_wait_pct.open": None,
 }
 BREAKDOWN = {
     "device_ops": [
