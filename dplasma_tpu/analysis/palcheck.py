@@ -453,12 +453,15 @@ def _cap_pallas_kernels(out: List[PallasContract]) -> None:
 
 def _cap_pallas_lu(out: List[PallasContract]) -> None:
     """kernels/pallas_lu.py: the blocked LU panel (whole-panel VMEM
-    residency, no grid)."""
+    residency, no grid) and the no-pivot tile LU (whole tile)."""
     import jax.numpy as jnp
     from dplasma_tpu.kernels import pallas_lu
     a = jnp.zeros((32, 16), jnp.float32)
     with capture("dplasma_tpu/kernels/pallas_lu.py:lu_panel", out):
         pallas_lu._panel_call.__wrapped__(a, True)
+    t = jnp.zeros((128, 128), jnp.float32)
+    with capture("dplasma_tpu/kernels/pallas_lu.py:lu_nopiv_tile", out):
+        pallas_lu._nopiv_call.__wrapped__(t, True)
 
 
 def _cap_pallas_qr(out: List[PallasContract]) -> None:

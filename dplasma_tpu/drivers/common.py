@@ -1582,6 +1582,9 @@ class Driver:
         lbl = dict(op=summary.get("op", self.name), prec=self.ip.prec)
         reg.gauge("refine_iterations", **lbl).set(
             summary.get("iterations", 0))
+        if summary.get("krylov_steps") is not None:
+            reg.gauge("refine_krylov_steps", **lbl).set(
+                summary["krylov_steps"])
         reg.counter("refine_escalations_total", **lbl).inc(
             1 if summary.get("escalated") else 0)
         hist = summary.get("backward_errors") or []

@@ -688,6 +688,28 @@ def gesv_ir(drv: Driver):
     return 0
 
 
+def gesv_mxp(drv: Driver):
+    """testing_dgesv_mxp: HPL-MxP's solve (ops.refine.gesv_mxp) — LU
+    without pivoting at bf16 trailing updates, GMRES-IR to f64 — on the
+    deployment's diagonally dominant matrix (dplghe, bump N), priced at
+    HPL's 2/3 N^3 + 3/2 N^2. No fallback: a solve that does not
+    converge fails its check."""
+    from dplasma_tpu.ops import refine
+    ip = drv.ip
+    A0 = _gen(drv, ip.N, ip.N, 0, kind="he")
+    B = _gen(drv, ip.N, ip.K, 1)
+    out, _ = drv.progress(
+        refine.gesv_mxp, (_put(drv, A0), _put(drv, B)),
+        2.0 / 3.0 * ip.N ** 3 + 1.5 * ip.N ** 2)
+    X, info = out
+    drv.report_refine(refine.summarize(info, op=drv.name,
+                                       precision="bf16"))
+    if ip.check:
+        r, ok = checks.check_solve(A0, B, X)
+        return drv.report_check("GESV_MXP backward error", r, ok)
+    return 0
+
+
 def gels_ir(drv: Driver):
     """testing_dgels_ir: overdetermined least squares by low-precision
     QR + semi-normal-equation refinement on the R factor (see
@@ -1088,6 +1110,7 @@ DRIVERS = {
     "gesv": gesv, "gesv_incpiv": gesv_incpiv,
     # mixed-precision iterative-refinement solvers (ops.refine)
     "posv_ir": posv_ir, "gesv_ir": gesv_ir, "gels_ir": gels_ir,
+    "gesv_mxp": gesv_mxp,
     "heev": heev, "hetrd": hetrd, "gesvd": gesvd, "gebrd": gebrd,
     "hetrf": hetrf, "hebut": hebut,
     "lange": lange, "lanhe": lanhe, "lansy": lansy, "lantr": lantr,

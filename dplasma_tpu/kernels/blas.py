@@ -102,6 +102,18 @@ def dot(a, b, ta: bool = False, tb: bool = False, conj_a: bool = False,
     return _inject.tap("gemm", out.astype(res_dtype))
 
 
+def dot_in(a, b, dtype=None):
+    """``a @ b`` with both operands rounded to ``dtype`` and the product
+    accumulated in ``a``'s dtype: one MXU pass for bf16, the trailing
+    updates of a factorization whose error a refinement removes.
+    ``dtype`` None is :func:`dot`."""
+    if dtype is None:
+        return dot(a, b)
+    out = jnp.matmul(a.astype(dtype), b.astype(dtype),
+                     preferred_element_type=a.dtype)
+    return _inject.tap("gemm", out)
+
+
 def gemm(alpha, a, b, beta, c, ta=False, tb=False, conj_a=False, conj_b=False):
     """C = alpha op(A) op(B) + beta C (CORE_zgemm semantics).
 
@@ -228,7 +240,13 @@ def herk(alpha, a, beta, c, *, lower=True, trans="N"):
 
 def getrf_nopiv(a):
     """LU without pivoting of one tile (CORE_zgetrf_nopiv): returns packed
-    L\\U (unit L implicit)."""
+    L\\U (unit L implicit). On the TPU an eligible f32 tile is factored
+    in one Pallas kernel (:func:`~dplasma_tpu.kernels.pallas_lu.
+    lu_nopiv_tile`); elsewhere, and for other tiles, by an XLA loop of
+    rank-1 updates."""
+    from dplasma_tpu.kernels import pallas_lu
+    if jax.default_backend() == "tpu" and pallas_lu.nopiv_eligible(a):
+        return _inject.tap("getrf", pallas_lu.lu_nopiv_tile(a))
     n = a.shape[0]
 
     def body(k, m):

@@ -55,6 +55,9 @@ def _plan(K: int, bits: int):
     return w, nl, min(K, kc)
 
 
+#: columns per row scale of :class:`LimbMatrix`'s limbs
+LIMB_COLS = 512
+
 # Back-compat alias for the chunk-depth constant (tests poke it to
 # build deep-K cases); the real value is now plan-dependent.
 KC = _plan(2 ** 20, 53)[2]
@@ -296,6 +299,150 @@ def mm(a, b, bits: int = 53):
             [jnp.imag(b), jnp.real(b)], axis=0), bits=bits)
         return (re + 1j * im).astype(jnp.complex128)
     return gemm_f64(a, b, bits=bits)
+
+
+class LimbMatrix:
+    """A matrix split ONCE into int8 limbs, for many f64-equivalent
+    products ``a @ x`` with narrow ``x`` (the matvecs of a Krylov
+    refinement): each product splits only ``x``.
+
+    The limbs are scaled per row in blocks of :data:`LIMB_COLS`
+    columns, not per whole row: in a diagonally dominant row the
+    diagonal sets a whole-row scale some log2(N) bits above its other
+    entries, whose limbs would then drop those bits. Every limb pair is
+    multiplied (not only the ``i + j < nl`` of :func:`gemm_f64`), so
+    the product is as exact as the split. Each block's level sums are
+    exact in int32 and recombined in f64 on their own scales, then
+    summed.
+
+    Under an active mesh whose grid divides ``a`` (rows sharded on
+    'p', columns on 'q'), the split and every product run per device
+    in a ``shard_map`` on its own block of ``a`` and of the replicated
+    ``x``; the partial products, carried across the chips as three
+    f32 words that add up to them exactly, are summed along 'q' and
+    gathered along 'p'. The answer comes back replicated. Real f64
+    only."""
+
+    def __init__(self, a):
+        from dplasma_tpu.parallel import mesh as pmesh
+        if not jax.config.jax_enable_x64:
+            raise RuntimeError("LimbMatrix requires jax_enable_x64")
+        a = jnp.asarray(a, jnp.float64)
+        m = pmesh.active()
+        M, K = a.shape
+        grid = (1, 1) if m is None else (m.shape[pmesh.ROW_AXIS],
+                                         m.shape[pmesh.COL_AXIS])
+        self.mesh = m if (grid != (1, 1) and M % grid[0] == 0
+                          and K % grid[1] == 0) else None
+        if self.mesh is None:
+            grid = (1, 1)
+        self.shape = (M, K)
+        self.kl = K // grid[1]                   # columns per device
+        self.w, self.nl, _ = _plan(LIMB_COLS, 53)
+        self.cw = min(LIMB_COLS, self.kl)
+        self.nch = -(-self.kl // self.cw)
+        if self.mesh is None:
+            self.limbs, self.scale, self.bad = self._split(a)
+            return
+        from jax.sharding import PartitionSpec as P
+        row, col = pmesh.ROW_AXIS, pmesh.COL_AXIS
+
+        def split(x):
+            limbs, scale, bad = self._split(x)
+            return limbs, scale, jax.lax.pmax(bad.astype(jnp.int32),
+                                              col) > 0
+
+        self.limbs, self.scale, self.bad = jax.shard_map(
+            split, mesh=self.mesh, in_specs=P(row, col),
+            out_specs=(P(col, row), P(col, row), P(row)),
+            check_vma=False)(a)
+
+    def _blocks(self, x):
+        """(rows, K) -> (nch, rows, cw), the last block zero-padded."""
+        pad = self.nch * self.cw - x.shape[1]
+        if pad:
+            x = jnp.pad(x, ((0, 0), (0, pad)))
+        return x.reshape(x.shape[0], self.nch, self.cw).transpose(1, 0, 2)
+
+    def _split(self, a):
+        """(nl int8 limbs (nch, rows, cw), their scales (nch, rows, 1),
+        non-finite rows (rows, 1)) of one block of rows."""
+        from dplasma_tpu.observability import phases
+        with phases.span("split", timed=False):
+            a3 = self._blocks(a)
+            mx = jnp.max(jnp.abs(a3), axis=2, keepdims=True)
+            scale = _pow2_scale_bits(mx)
+            bad = jnp.any(~jnp.isfinite(mx), axis=0)
+            return _split_fixed(a3, scale, self.w, self.nl), scale, bad
+
+    def _partial(self, limbs, scale, x):
+        """This block's ``a @ x`` in f64, ``x`` its (kl, c) rows."""
+        from dplasma_tpu.observability import phases
+        w, nl = self.w, self.nl
+        with phases.span("split", timed=False):
+            x3 = self._blocks(x.T).transpose(0, 2, 1)     # (nch, cw, c)
+            xscale = _pow2_scale_bits(jnp.max(jnp.abs(x3), axis=1,
+                                              keepdims=True))
+            bl = _split_fixed(x3, xscale, w, nl)
+        c = x.shape[1]
+        bfull = jnp.concatenate(bl, axis=2)            # (nch, cw, nl*c)
+        dn = (((2,), (1,)), ((0,), (0,)))
+        # every limb pair, not only i + j < nl: the product is then as
+        # exact as the split, and a matvec costs the read of the limbs
+        levels = [None] * (2 * nl - 1)
+        for i in range(nl):
+            p = jax.lax.dot_general(limbs[i], bfull, dn,
+                                    preferred_element_type=jnp.int32)
+            for j in range(nl):
+                pj = p[..., j * c:(j + 1) * c]
+                levels[i + j] = pj if levels[i + j] is None \
+                    else levels[i + j] + pj
+        with phases.span("recombine", timed=False):
+            u = _level_recombine(levels, w) * (scale * xscale)
+            return jnp.sum(u, axis=0)
+
+    def matmul(self, x):
+        """``a @ x`` at f64-equivalent accuracy for a replicated ``x``
+        of shape (K, c); replicated (M, c) out. A non-finite entry of
+        ``a`` or ``x`` makes its row or column NaN."""
+        from dplasma_tpu.parallel import mesh as pmesh
+        x = jnp.asarray(x, jnp.float64)
+        xbad = ~jnp.isfinite(jnp.max(jnp.abs(x), axis=0, keepdims=True))
+        if self.mesh is None:
+            out = self._partial(self.limbs, self.scale, x)
+        else:
+            from jax.sharding import PartitionSpec as P
+            row, col = pmesh.ROW_AXIS, pmesh.COL_AXIS
+            kl = self.kl
+
+            def body(limbs, scale, xr):
+                q = jax.lax.axis_index(col)
+                xl = jax.lax.dynamic_slice_in_dim(xr, q * kl, kl, axis=0)
+                part = self._partial(limbs, scale, xl)
+                # three f32 words that add up to the f64 partial exactly
+                w1 = part.astype(jnp.float32)
+                r1 = part - w1.astype(jnp.float64)
+                w2 = r1.astype(jnp.float32)
+                w3 = (r1 - w2.astype(jnp.float64)).astype(jnp.float32)
+                words = jnp.stack([w1, w2, w3])
+                words = jax.lax.all_gather(words, col)
+                return jax.lax.all_gather(words, row, axis=2, tiled=True)
+
+            words = jax.shard_map(
+                body, mesh=self.mesh,
+                in_specs=(P(col, row), P(col, row), P()),
+                out_specs=P(), check_vma=False)(self.limbs, self.scale,
+                                                x)
+            parts = jnp.sum(words.astype(jnp.float64), axis=1)
+            out = parts[0]
+            for k in range(1, parts.shape[0]):
+                out = out + parts[k]
+        return jnp.where(self.bad | xbad, jnp.nan, out)
+
+    def residual(self, base, x):
+        """``base - a @ x`` at f64-equivalent accuracy (see
+        :meth:`matmul`)."""
+        return jnp.asarray(base, jnp.float64) - self.matmul(x)
 
 
 # ---------------------------------------------------------------------

@@ -157,3 +157,56 @@ def lu_panel(a, interpret: bool | None = None):
 
     perm = jax.lax.fori_loop(0, a.shape[1], body, perm)
     return packed, perm
+
+# ---------------------------------------------------------------------
+# LU without pivoting of one square tile
+# ---------------------------------------------------------------------
+
+#: largest tile :func:`lu_nopiv_tile` takes: the step temporaries of a
+#: 1024 tile ask the TPU compiler for 16.3 of its 16 MiB of scoped VMEM
+NOPIV_MAX = 512
+
+
+def _nopiv_kernel(a_ref, out_ref):
+    n = a_ref.shape[0]
+    r1 = jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
+    c1 = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+
+    def step(j, A):
+        # column j and row j by masked reductions: no dynamic slicing
+        colj = jnp.sum(jnp.where(c1 == j, A, 0.0), axis=1, keepdims=True)
+        rowj = jnp.sum(jnp.where(r1 == j, A, 0.0), axis=0, keepdims=True)
+        d = jnp.sum(jnp.where(r1 == j, colj, 0.0), axis=0, keepdims=True)
+        lcol = jnp.where(r1 > j, colj / d, 0.0)
+        A = A - lcol * jnp.where(c1 > j, rowj, 0.0)
+        return jnp.where((c1 == j) & (r1 > j), lcol, A)
+
+    out_ref[...] = jax.lax.fori_loop(0, n, step, a_ref[...])
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def _nopiv_call(a, interpret: bool):
+    return pl.pallas_call(
+        _nopiv_kernel,
+        out_shape=jax.ShapeDtypeStruct(a.shape, jnp.float32),
+        interpret=interpret,
+    )(a)
+
+
+def nopiv_eligible(a) -> bool:
+    """f32 square tile, lane-aligned, at most :data:`NOPIV_MAX`."""
+    return (a.ndim == 2 and a.dtype == jnp.float32
+            and a.shape[0] == a.shape[1] and a.shape[0] % 128 == 0
+            and a.shape[0] <= NOPIV_MAX)
+
+
+def lu_nopiv_tile(a, interpret: bool | None = None):
+    """Packed L\\U (unit L implicit) of a square f32 tile without
+    pivoting, in one kernel: the tile stays in VMEM and each of its n
+    elimination steps is a handful of whole-tile vector passes (the
+    contract of :func:`dplasma_tpu.kernels.blas.getrf_nopiv`, whose
+    XLA loop runs each step as separate operations)."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    with jax.enable_x64(False):
+        return _nopiv_call(jnp.asarray(a, jnp.float32), interpret)

@@ -59,7 +59,8 @@ Schema (stable keys; additive changes bump ``REPORT_SCHEMA``)::
                                      "detail"}]}],         # (v6)
      "refine": [{"op", "precision", "iterations",
                  "backward_errors": [...], "converged",
-                 "escalated", "tol"}],                     # (v7)
+                 "escalated", "tol",
+                 "krylov_steps"}],     # (v7; krylov_steps: gesv_mxp)
      "serving": [{"requests", "batches", "mean_batch",
                   "latency_s": {"p50", "p99", "max"},
                   "cache": {"entries", "capacity", "hits", "misses",

@@ -96,25 +96,38 @@ def laswp(A: TileMatrix, perm, inverse: bool = False) -> TileMatrix:
 
 # -- no-pivoting LU ----------------------------------------------------
 
-def _lu_apply_block(pan, blk, bw: int, perm=None):
+def _lu_apply_block(pan, blk, bw: int, perm=None, update_dtype=None):
     """Apply one factored LU panel to a column block: optional pivot
     gather, U solve of the top bw rows, rank-bw Schur update below.
     The shared narrow/wide update of the pipelined sweep; the Schur
     product routes through the block-scaled int8 GEMM under the
-    ir.precision=int8 rung (kernels.quant.update_scope) — the U solve
-    stays f32, it writes factor output."""
+    ir.precision=int8 rung (kernels.quant.update_scope), or takes
+    ``update_dtype`` operands (:func:`~dplasma_tpu.kernels.blas.dot_in`)
+    — the U solve stays f32, it writes factor output."""
     if perm is not None:
         blk = blk[perm]
     u = k.trsm(pan[:bw], blk[:bw], side="L", lower=True, unit=True)
     below = blk[bw:]
     if below.shape[0]:
-        below = below - _quant.update_dot(pan[bw:], u)
+        below = below - (_quant.update_dot(pan[bw:], u)
+                         if update_dtype is None
+                         else k.dot_in(pan[bw:], u, update_dtype))
     return u, below
 
 
-def getrf_nopiv(A: TileMatrix, lookahead=None) -> TileMatrix:
+def getrf_nopiv(A: TileMatrix, lookahead=None,
+                update_dtype=None) -> TileMatrix:
     """Blocked right-looking LU without pivoting
     (dplasma_zgetrf_nopiv). Returns packed L\\U (unit L implicit).
+    ``update_dtype`` (``jnp.bfloat16``) rounds the trailing updates'
+    operands to it, accumulating in ``A``'s dtype.
+
+    Under an active mesh with a nontrivial process grid the factor is
+    the block-cyclic sweep without pivoting
+    (:func:`dplasma_tpu.parallel.cyclic.getrf_cyclic`); the returned
+    LU then carries the cyclic factor with the identity ``perm``
+    (``LU.cyclic_factor``, as :func:`getrf_ptgpanel`'s does), so that
+    :func:`getrs` with :func:`nopiv_perm` solves on the slabs.
 
     Lookahead-pipelined shrinking-window sweep
     (:func:`dplasma_tpu.ops._sweep.pipelined_sweep`): the next panel's
@@ -125,8 +138,13 @@ def getrf_nopiv(A: TileMatrix, lookahead=None) -> TileMatrix:
     ``sweep.lookahead 0``) is the serialized baseline, bit-identical
     op order."""
     from dplasma_tpu.kernels import panels as _panels
+    from dplasma_tpu.observability import phases
     from dplasma_tpu.ops import _sweep
     assert A.desc.mb == A.desc.nb, "getrf needs square tiles"
+    if _grid_of(A) is not None:
+        with phases.span("getrf", timed=False):
+            LU, _ = _getrf_grid(A, pivot=False, update_dtype=update_dtype)
+            return LU
     la, _ = _sweep.sweep_params(lookahead)
     nb = A.desc.nb
     KT = A.desc.KT
@@ -150,9 +168,21 @@ def getrf_nopiv(A: TileMatrix, lookahead=None) -> TileMatrix:
 
     packs, urows = _sweep.pipelined_sweep(
         rest, nb, KT, NT, panel,
-        lambda pan, blk: _lu_apply_block(pan, blk, nb), lookahead=la)
+        lambda pan, blk: _lu_apply_block(pan, blk, nb,
+                                         update_dtype=update_dtype),
+        lookahead=la)
     full = assemble_sweep(packs, urows, KT, NT, nb)
     return TileMatrix(pmesh.constrain2d(full), A.desc)
+
+
+def nopiv_perm(LU: TileMatrix):
+    """The row order of a :func:`getrf_nopiv` factor, the identity: the
+    very ``perm`` its cyclic factor carries (so that :func:`getrs` solves
+    on the slabs), else a new one over the padded rows."""
+    twin = getattr(LU, "cyclic_factor", None)
+    if twin is not None:
+        return twin[1]
+    return jnp.arange(LU.desc.MT * LU.desc.mb, dtype=jnp.int32)
 
 
 # -- partial pivoting (1d / ptgpanel) ----------------------------------
@@ -493,29 +523,45 @@ def getrf_ptgpanel(A: TileMatrix):
     conversion back."""
     from dplasma_tpu.observability import phases
     with phases.span("getrf", timed=False):
-        m = pmesh.active()
-        if m is not None and A.desc.mb == A.desc.nb:
-            P = m.shape[pmesh.ROW_AXIS]
-            Q = m.shape[pmesh.COL_AXIS]
-            if P * Q > 1:
-                from dplasma_tpu.descriptors import Dist
-                from dplasma_tpu.parallel import cyclic
-                d = A.desc.dist
-                if (d.P, d.Q) != (P, Q):  # grid comes from the mesh;
-                    d = Dist(P=P, Q=Q)    # keep dist's kp/kq if it fits
-                with phases.span("redistribute", timed=False):
-                    C = cyclic.CyclicMatrix.from_tile(A, d)
-                F, perm = cyclic.getrf_cyclic(C)
-                with phases.span("redistribute", timed=False):
-                    full = F.to_tile().data[perm]
-                    full = pmesh.constrain2d(full)
-                LU = TileMatrix(full, A.desc)
-                # a plain attribute, not a pytree field: it does not
-                # cross a jit boundary, so only a getrs traced with the
-                # factor (and with this perm) solves on the slabs
-                LU.cyclic_factor = (F, perm)
-                return LU, perm
+        if A.desc.mb == A.desc.nb and _grid_of(A) is not None:
+            return _getrf_grid(A)
         return getrf_1d(A)
+
+
+def _grid_of(A: TileMatrix):
+    """The Dist of the active mesh's nontrivial process grid (keeping
+    ``A``'s kp/kq where its grid is the mesh's), or None."""
+    m = pmesh.active()
+    if m is None:
+        return None
+    P = m.shape[pmesh.ROW_AXIS]
+    Q = m.shape[pmesh.COL_AXIS]
+    if P * Q == 1:
+        return None
+    from dplasma_tpu.descriptors import Dist
+    d = A.desc.dist
+    return d if (d.P, d.Q) == (P, Q) else Dist(P=P, Q=Q)
+
+
+def _getrf_grid(A: TileMatrix, pivot: bool = True, update_dtype=None):
+    """The block-cyclic LU of ``A`` on the active grid, as ``(LU,
+    perm)`` in natural order with the cyclic factor attached."""
+    from dplasma_tpu.observability import phases
+    from dplasma_tpu.parallel import cyclic
+    with phases.span("redistribute", timed=False):
+        C = cyclic.CyclicMatrix.from_tile(A, _grid_of(A))
+    F, perm = cyclic.getrf_cyclic(C, pivot=pivot, update_dtype=update_dtype)
+    with phases.span("redistribute", timed=False):
+        full = F.to_tile().data
+        if pivot:
+            full = full[perm]
+        full = pmesh.constrain2d(full)
+    LU = TileMatrix(full, A.desc)
+    # a plain attribute, not a pytree field: it does not cross a jit
+    # boundary, so only a getrs traced with the factor (and with this
+    # perm) solves on the slabs
+    LU.cyclic_factor = (F, perm)
+    return LU, perm
 
 
 def trsmpl_ptgpanel(LU: TileMatrix, perm, B: TileMatrix) -> TileMatrix:

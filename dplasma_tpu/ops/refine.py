@@ -63,6 +63,11 @@ output is unhealthy walks the ladder like any other fault. The
 non-finite census on the backward error doubles as the convergence
 guard (a NaN residual is divergence, not a verdict).
 
+:func:`gesv_mxp` is HPL-MxP's solve: the LU without pivoting at bf16
+trailing updates, refined by restarted GMRES (right-preconditioned by
+that LU) instead of the classical step, in one ``lax.while_loop``
+that stops at convergence, with no escalation.
+
 Every stage carries a phase span (``factor`` / ``solve`` /
 ``residual`` / ``correct`` / ``escalate``) for the PR 5 attribution
 ledger; :func:`dplasma_tpu.observability.roofline.refine_phase_model`
@@ -543,6 +548,189 @@ def gels_ir(A: TileMatrix, B: TileMatrix, *, precision=None,
     return _tile(x, B), info
 
 
+#: Krylov vectors per GMRES cycle of :func:`gesv_mxp` (restart length)
+MXP_RESTART = 8
+#: most GMRES cycles :func:`gesv_mxp` runs before it gives up
+MXP_MAX_CYCLES = 4
+
+
+def gesv_mxp(A: TileMatrix, B: TileMatrix, *, tol=None):
+    """General solve A X = B as HPL-MxP runs it: LU WITHOUT pivoting of
+    an f32 copy of ``A`` with bf16 trailing updates (one MXU pass, f32
+    accumulation), then GMRES-based iterative refinement to
+    f64-equivalent backward error. Returns ``(X, info)`` as
+    :func:`gesv_ir` does; ``info`` adds ``krylov_steps``.
+
+    The factor is :func:`~dplasma_tpu.ops.lu.getrf_nopiv` (under a
+    device mesh, the block-cyclic sweep without pivoting); it must not
+    need pivots, as HPL-MxP's diagonally dominant matrix does not.
+    Refinement: restarted GMRES (:data:`MXP_RESTART` vectors, at most
+    :data:`MXP_MAX_CYCLES` cycles) on the right-preconditioned system
+    ``A M^-1``, with ``M^-1 = U^-1 L^-1`` applied by
+    :func:`~dplasma_tpu.ops.lu.getrs` in f32 (on the slabs under a
+    mesh). The outer residuals ``b - A x`` and the Arnoldi products
+    ``A z`` are f64-equivalent through one
+    :class:`~dplasma_tpu.kernels.dd.LimbMatrix` (``A`` split once per
+    call); Gram-Schmidt (twice, classical) and the Hessenberg
+    least squares run in f64 on replicated vectors, one system per
+    column of ``B``. The solve starts at x = 0, so the first Krylov
+    vector is the low-precision solve ``M^-1 b``. A cycle stops once
+    its residual estimate meets the tolerance (MCA ``ir.tol``; 100 f64
+    eps by default) in the normwise backward error, and the whole solve
+    once the true residual does, or once a cycle fails to halve it (the
+    floor of the arithmetic: the TPU's f64 is an f32 pair, about 2^-48).
+    There is no escalation: a solve that does not converge says so in
+    ``info["converged"]``."""
+    from dplasma_tpu.ops import lu as lu_mod
+    _require_f64(A, "gesv_mxp")
+    _, _, tol_ = ir_params("f32", 1, tol)
+    f64t = A.dtype
+    f32 = jnp.float32
+    ad = A.to_dense().astype(f64t)
+    bd = B.to_dense().astype(f64t)
+    tiny = float(jnp.finfo(f64t).tiny)
+
+    with phases.span("factor") as _f:
+        LUw = lu_mod.getrf_nopiv(_tile(ad.astype(f32), A),
+                                 update_dtype=jnp.bfloat16)
+        _f(LUw.data)
+    perm = lu_mod.nopiv_perm(LUw)
+
+    def precond(v):
+        out = lu_mod.getrs("N", LUw, perm, _tile(v.astype(f32), B))
+        return out.to_dense().astype(f64t)
+
+    op = _dd.LimbMatrix(ad)
+    anorm, bnorm = _maxabs(ad), _maxabs(bd)
+    backward = _backward_fn(anorm, bnorm, tiny)
+
+    def residual(xv):
+        with phases.span("residual"):
+            return op.residual(bd, xv)
+
+    def cycle(r, xv):
+        # the estimate is a 2-norm, at least the inf-norm it stands for
+        limit = tol_ * (anorm * _maxabs(xv) + bnorm)
+        with phases.span("correct"):
+            return _gmres_cycle(r, limit, precond, op.matmul)
+
+    hist0 = jnp.full((MXP_MAX_CYCLES + 1,), -1.0, f64t)
+
+    def record(hist, it, bwd):
+        return hist.at[it].set(jnp.where(jnp.isfinite(bwd),
+                                         bwd.astype(f64t), -1.0))
+
+    # x0 = 0: the first Krylov vector is the low-precision solve M^-1 b,
+    # so the program traces one preconditioner solve and one residual
+    x = jnp.zeros_like(bd)
+    bwd = backward(bd, x)
+    zero = jnp.asarray(0, jnp.int32)
+    state = (zero, x, bd, bwd, bwd <= tol_, record(hist0, 0, bwd), zero)
+
+    def more(st):
+        it, _, _, _, stop, _, _ = st
+        return ~stop & (it < MXP_MAX_CYCLES)
+
+    def step(st):
+        it, xv, rv, prev, _, hist, ks = st
+        d, steps = cycle(rv, xv)
+        xv = xv + d
+        rv = residual(xv)
+        bwd = backward(rv, xv)
+        # a cycle that does not halve the backward error has met the
+        # floor of the arithmetic (or diverged): more cycles cannot help
+        stop = (bwd <= tol_) | ~(bwd <= 0.5 * prev)
+        return (it + 1, xv, rv, bwd, stop, record(hist, it + 1, bwd),
+                ks + steps)
+
+    it, x, _, bwd, _, hist, ks = lax.while_loop(more, step, state)
+    info = {"backward_errors": hist, "iterations": it,
+            "converged": bwd <= tol_, "escalated": jnp.asarray(False),
+            "krylov_steps": ks}
+    return _tile(x, B), info
+
+
+def _gmres_cycle(r, limit, precond, matvec):
+    """One restarted-GMRES cycle on ``A M^-1 u = r`` for each column of
+    ``r`` (N, c), started at u = 0: ``(d, steps)`` with ``d = M^-1 u``
+    the correction (the preconditioned Krylov vectors ``z_j = M^-1
+    v_j`` are kept, as flexible GMRES keeps them) and ``steps`` the
+    Krylov steps taken (the most over the columns). A column stops
+    once its residual estimate is at most ``limit``; the cycle, once
+    every column has stopped or :data:`MXP_RESTART` steps are done."""
+    m = MXP_RESTART
+    n, c = r.shape
+    f64t = r.dtype
+
+    def nrm(v):
+        return jnp.sqrt(jnp.sum(v * v, axis=0))
+
+    beta = nrm(r)
+    V = jnp.zeros((m + 1, n, c), f64t).at[0].set(
+        r / jnp.where(beta > 0, beta, 1.0))
+    Z = jnp.zeros((m, n, c), f64t)
+    H = jnp.zeros((m, m, c), f64t)           # R of the rotated Hessenberg
+    cs = jnp.zeros((m, c), f64t)
+    sn = jnp.zeros((m, c), f64t)
+    g = jnp.zeros((m + 1, c), f64t).at[0].set(beta)
+    live = beta > limit
+    steps = jnp.zeros((c,), jnp.int32)
+
+    def more(st):
+        j, live = st[0], st[7]
+        return (j < m) & jnp.any(live)
+
+    def step(st):
+        j, V, Z, H, cs, sn, g, live, steps = st
+        z = precond(V[j])
+        w = matvec(z)
+        # classical Gram-Schmidt, twice, against v_0..v_j
+        past = (jnp.arange(m + 1) <= j)[:, None]
+        h = jnp.zeros((m + 1, c), f64t)
+        for _ in range(2):
+            hk = jnp.where(past, jnp.sum(V * w[None], axis=1), 0.0)
+            w = w - jnp.sum(hk[:, None, :] * V, axis=0)
+            h = h + hk
+        hn = nrm(w)
+        V = V.at[j + 1].set(w / jnp.where(hn > 0, hn, 1.0))
+        Z = Z.at[j].set(z)
+        # the rotations of the earlier steps, then a new one for hn
+        for i in range(m - 1):
+            hi, hi1 = h[i], h[i + 1]
+            on = i < j
+            h = h.at[i].set(jnp.where(on, cs[i] * hi + sn[i] * hi1, hi))
+            h = h.at[i + 1].set(jnp.where(on, cs[i] * hi1 - sn[i] * hi,
+                                          hi1))
+        hj = h[j]
+        rad = jnp.sqrt(hj * hj + hn * hn)
+        safe = jnp.where(rad > 0, rad, 1.0)
+        cj, sj = jnp.where(rad > 0, hj / safe, 1.0), hn / safe
+        col = h[:m].at[j].set(rad)
+        H = H.at[:, j].set(jnp.where(live, col, H[:, j]))
+        cs = cs.at[j].set(jnp.where(live, cj, cs[j]))
+        sn = sn.at[j].set(jnp.where(live, sj, sn[j]))
+        gj = g[j]
+        g = jnp.where(live, g.at[j].set(cj * gj).at[j + 1].set(-sj * gj),
+                      g)
+        steps = steps + live.astype(jnp.int32)
+        live = live & (jnp.abs(g[j + 1]) > limit)
+        return (j + 1, V, Z, H, cs, sn, g, live, steps)
+
+    st = lax.while_loop(more, step, (jnp.asarray(0, jnp.int32), V, Z, H,
+                                     cs, sn, g, live, steps))
+    _, _, Z, H, _, _, g, _, steps = st
+    # back substitution on each column's own steps
+    y = [None] * m
+    for i in range(m - 1, -1, -1):
+        acc = g[i]
+        for k in range(i + 1, m):
+            acc = acc - H[i, k] * y[k]
+        on = i < steps
+        y[i] = jnp.where(on, acc / jnp.where(on, H[i, i], 1.0), 0.0)
+    d = jnp.sum(jnp.stack(y)[:, None, :] * Z, axis=0)
+    return d, jnp.max(steps)
+
+
 # ---------------------------------------------------------------------
 # Reporting helpers
 # ---------------------------------------------------------------------
@@ -562,6 +750,8 @@ def summarize(info, *, op: str, precision=None, tol=None) -> dict:
            "converged": bool(np.asarray(info["converged"])),
            "escalated": bool(np.asarray(info["escalated"])),
            "tol": tol_}
+    if "krylov_steps" in info:
+        out["krylov_steps"] = int(np.asarray(info["krylov_steps"]))
     if "quant_guard_max" in info:
         # int8 rung: the max ABFT ones-probe residual over the routed
         # trailing updates (the per-update divergence guard)

@@ -204,10 +204,10 @@ def _a2a_phase(x, axis_name, nt: int, tb: int, P: int, kp: int,
             sg = l * P + r_eff
             s_src = sg // c
             jj = (sg - s_src * c) // P
-            picked = recv[s_src]                         # (c,per*stb,W)
             rows2 = (jj[:, None] * stb + jnp.arange(stb)).reshape(-1)
-            out = picked[jnp.arange(c).repeat(stb), rows2].reshape(
-                c * stb, W)
+            # one gather by (source, row): never the (c, per*stb, W)
+            # stack of whole pieces that recv[s_src] would build
+            out = recv[s_src.repeat(stb), rows2].reshape(c * stb, W)
         else:             # cyclic -> contiguous
             # send[d] = my slots whose global supertile lies in d's
             # contiguous range — per CONSECUTIVE slots from d*c//P
@@ -220,10 +220,8 @@ def _a2a_phase(x, axis_name, nt: int, tb: int, P: int, kp: int,
             g = me * c + t
             s_src = (g % P + ip) % P
             jj = t // P
-            picked = recv[s_src]
             rows2 = (jj[:, None] * stb + jnp.arange(stb)).reshape(-1)
-            out = picked[jnp.arange(c).repeat(stb), rows2].reshape(
-                c * stb, W)
+            out = recv[s_src.repeat(stb), rows2].reshape(c * stb, W)
         return out if row_axis else out.T
 
     spec = PartitionSpec(pmesh.ROW_AXIS, pmesh.COL_AXIS)
@@ -486,10 +484,11 @@ def _potrf_cyclic_jit(data, desc: CyclicDesc, mesh, lookahead: int = 0,
     return f(data)
 
 
-@partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6))
+@partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6, 7, 8))
 def _getrf_cyclic_jit(data, desc: CyclicDesc, mesh,
                       lookahead: int = 0, panel: str = "chain",
-                      ring: bool = False, rchunks: int = 0):
+                      ring: bool = False, rchunks: int = 0,
+                      pivot: bool = True, update_dtype=None):
     """Distributed tournament-pivoting LU over cyclic local slabs —
     the reference's hand-distributed parallel panel
     (src/zgetrf_ptgpanel.jdf: per-rank panel elimination + pivot
@@ -502,6 +501,15 @@ def _getrf_cyclic_jit(data, desc: CyclicDesc, mesh,
     their owners' slabs (pivoting is deferred to the returned global
     permutation, never materialized as row motion — on TPU a gather at
     the end beats KT rounds of row swaps over ICI).
+
+    ``pivot=False`` elects nothing: the rows of tile k are step k's
+    winners, their current values reach every row rank by the
+    exchange's masked psum (or ring) and every rank factors the
+    diagonal block without pivoting — the elimination order is the
+    identity (the rest of the step is shared). ``update_dtype``
+    (``jnp.bfloat16``) rounds the Schur update's and the lookahead's
+    operands to it, accumulating in the slabs' dtype; the panel's
+    triangular solves keep the slabs' precision.
 
     Returns (local factor slabs, win_gids (KT, mb) global element-row
     ids in elimination order, active_left (P, mloc) bools)."""
@@ -537,64 +545,93 @@ def _getrf_cyclic_jit(data, desc: CyclicDesc, mesh,
                 else:
                     pan = pan_next
                 panm = jnp.where(active[:, None], pan, 0)
-                # 2) local candidate election (one local LU per
-                #    row-rank, concurrently across 'p' — the distributed
-                #    panel). The panel engine selects the
-                #    election/playoff kernel: rec = the
-                #    blocked-recursive fused panel (kernels.panels, no
-                #    vendor custom call), chain = lax.linalg.lu
-                #    (bit-identical pre-engine route). Local work only —
-                #    the collective schedule is IDENTICAL either way
-                #    (spmdcheck's exact-count contract holds per kernel).
-                with span("elect", timed=False):
-                    if panel == "rec":
-                        from dplasma_tpu.kernels import panels as _panels
-                        _, cperm = _panels.lu_panel_rec(panm)
-                    else:
-                        _, _, cperm = jax.lax.linalg.lu(panm)
-                    cand_pos = cperm[:mb]                  # (mb,) local
-                    cands = panm[cand_pos]
-                # 3) playoff: all_gather candidates along 'p',
-                # replicated LU
-                with span("playoff", timed=False):
-                    allc = jax.lax.all_gather(cands, pmesh.ROW_AXIS)
-                    allid = jax.lax.all_gather(gid[cand_pos],
-                                               pmesh.ROW_AXIS)
-                    if panel == "rec":
-                        lu2, perm2 = _panels.lu_panel_rec(
-                            allc.reshape(P * mb, mb))
-                    else:
-                        lu2, _, perm2 = jax.lax.linalg.lu(
-                            allc.reshape(P * mb, mb))
-                    wr = perm2[:mb]                        # stack index
-                    win_gids = allid.reshape(P * mb)[wr]
-                    top = lu2[:mb]               # packed L11\U11 rows
-                wins.append(win_gids)
-                # 4) my winners -> local rows; retire them from the
-                # active set
-                mine = (wr // mb) == p
-                win_lrow = jnp.where(mine, cand_pos[wr % mb], mloc)
-                elim = jnp.zeros((mloc + 1,), bool).at[win_lrow].set(
-                    True, mode="drop")[:mloc]
-                # 5) winner rows' current values for MY columns (masked
-                #    psum along 'p' — the pivot-row exchange)
-                with span("exchange", timed=False):
-                    sel = jnp.where(mine[:, None],
-                                    A[jnp.where(mine, win_lrow, 0)], 0)
+                if pivot:
+                    # 2) local candidate election (one local LU per
+                    #    row-rank, concurrently across 'p' — the distributed
+                    #    panel). The panel engine selects the
+                    #    election/playoff kernel: rec = the
+                    #    blocked-recursive fused panel (kernels.panels, no
+                    #    vendor custom call), chain = lax.linalg.lu
+                    #    (bit-identical pre-engine route). Local work only —
+                    #    the collective schedule is IDENTICAL either way
+                    #    (spmdcheck's exact-count contract holds per kernel).
+                    with span("elect", timed=False):
+                        if panel == "rec":
+                            from dplasma_tpu.kernels import panels as _panels
+                            _, cperm = _panels.lu_panel_rec(panm)
+                        else:
+                            _, _, cperm = jax.lax.linalg.lu(panm)
+                        cand_pos = cperm[:mb]                  # (mb,) local
+                        cands = panm[cand_pos]
+                    # 3) playoff: all_gather candidates along 'p',
+                    # replicated LU
+                    with span("playoff", timed=False):
+                        allc = jax.lax.all_gather(cands, pmesh.ROW_AXIS)
+                        allid = jax.lax.all_gather(gid[cand_pos],
+                                                   pmesh.ROW_AXIS)
+                        if panel == "rec":
+                            lu2, perm2 = _panels.lu_panel_rec(
+                                allc.reshape(P * mb, mb))
+                        else:
+                            lu2, _, perm2 = jax.lax.linalg.lu(
+                                allc.reshape(P * mb, mb))
+                        wr = perm2[:mb]                        # stack index
+                        win_gids = allid.reshape(P * mb)[wr]
+                        top = lu2[:mb]               # packed L11\U11 rows
+                    wins.append(win_gids)
+                    # 4) my winners -> local rows; retire them from the
+                    # active set
+                    mine = (wr // mb) == p
+                    win_lrow = jnp.where(mine, cand_pos[wr % mb], mloc)
+                    elim = jnp.zeros((mloc + 1,), bool).at[win_lrow].set(
+                        True, mode="drop")[:mloc]
+                    # 5) winner rows' current values for MY columns (masked
+                    #    psum along 'p' — the pivot-row exchange)
+                    with span("exchange", timed=False):
+                        sel = jnp.where(mine[:, None],
+                                        A[jnp.where(mine, win_lrow, 0)], 0)
+                        if ring and P > 1:
+                            # winner rows ride the explicit 'p' ring: P-1
+                            # shift-and-add hops (kernels.pallas_ring).
+                            # Winner rows have exactly one owner, so the
+                            # contributions are disjoint and the sum is
+                            # bit-identical to psum's.
+                            from dplasma_tpu.kernels import pallas_ring \
+                                as _pring
+                            wrows = _pring.ring_allreduce(
+                                sel, axis=pmesh.ROW_AXIS,
+                                axes=((pmesh.ROW_AXIS, P),
+                                      (pmesh.COL_AXIS, Q)))
+                        else:
+                            wrows = jax.lax.psum(sel, pmesh.ROW_AXIS)
+                else:
+                    # 2-5) no election: the rows of tile k on their
+                    # owner row rank are the winners; their current
+                    # values (my columns, then the panel block) reach
+                    # every row rank by the exchange's masked psum
+                    pk = layout.owner(k, P, d.kp, d.ip)
+                    lrk = layout.local_index(k, P, d.kp)
+                    rows = slice(lrk * mb, (lrk + 1) * mb)
+                    mine = jnp.broadcast_to(p == pk, (mb,))
+                    win_lrow = jnp.where(mine, lrk * mb + jnp.arange(mb),
+                                         mloc)
+                    win_gids = k * mb + jnp.arange(mb, dtype=gid.dtype)
+                    sel = jnp.where(p == pk, jnp.concatenate(
+                        [A[rows], pan[rows]], axis=1), 0)
                     if ring and P > 1:
-                        # winner rows ride the explicit 'p' ring: P-1
-                        # shift-and-add hops (kernels.pallas_ring).
-                        # Winner rows have exactly one owner, so the
-                        # contributions are disjoint and the sum is
-                        # bit-identical to psum's.
                         from dplasma_tpu.kernels import pallas_ring \
                             as _pring
-                        wrows = _pring.ring_allreduce(
+                        both = _pring.ring_allreduce(
                             sel, axis=pmesh.ROW_AXIS,
                             axes=((pmesh.ROW_AXIS, P),
                                   (pmesh.COL_AXIS, Q)))
                     else:
-                        wrows = jax.lax.psum(sel, pmesh.ROW_AXIS)
+                        both = jax.lax.psum(sel, pmesh.ROW_AXIS)
+                    wrows = both[:, :nloc]
+                    top = kb.getrf_nopiv(both[:, nloc:])
+                    wins.append(win_gids)
+                    elim = jnp.zeros((mloc + 1,), bool).at[win_lrow].set(
+                        True, mode="drop")[:mloc]
                 u12 = kb.trsm(top, wrows, side="L", lower=True, unit=True)
                 trailing = (gcol > k)[None, :]
                 u12 = jnp.where(trailing, u12, 0)
@@ -616,7 +653,8 @@ def _getrf_cyclic_jit(data, desc: CyclicDesc, mesh,
                             A, lck1 * mb, mb, axis=1)
                         u12k1 = jax.lax.dynamic_slice_in_dim(
                             u12, lck1 * mb, mb, axis=1)
-                        coln = cs1 - kb.dot(l21, u12k1)
+                        coln = cs1 - kb.dot_in(l21, u12k1,
+                                               update_dtype)
                         coln = coln.at[win_lrow].set(
                             jnp.where(mine[:, None], u12k1,
                                       coln[jnp.where(mine, win_lrow, 0)]),
@@ -629,7 +667,7 @@ def _getrf_cyclic_jit(data, desc: CyclicDesc, mesh,
                                                 rchunks)
                 else:
                     pan_next = None
-                A = A - kb.dot(l21, u12)
+                A = A - kb.dot_in(l21, u12, update_dtype)
                 # 7) owners write the L column into the panel block
                 newcs = jnp.where((active & ~elim)[:, None], l21, cs)
                 A = jnp.where(q == qk,
@@ -667,12 +705,15 @@ def _getrf_cyclic_jit(data, desc: CyclicDesc, mesh,
     return f(data)
 
 
-def getrf_cyclic(A: CyclicMatrix):
+def getrf_cyclic(A: CyclicMatrix, pivot: bool = True,
+                 update_dtype=None):
     """Distributed partial-pivoting LU on block-cyclic local storage
     (the pdgetrf / zgetrf_ptgpanel shape). Returns
     (factor CyclicMatrix — rows in place, perm) with the
     :func:`dplasma_tpu.ops.lu.getrf_1d` contract ``A[perm] = L U``
-    after gathering rows by ``perm``."""
+    after gathering rows by ``perm``. ``pivot=False`` factors without
+    pivoting (``perm`` is the identity) and ``update_dtype`` sets the
+    trailing updates' operand type (:func:`_getrf_cyclic_jit`)."""
     m = pmesh.active()
     assert m is not None, "getrf_cyclic needs an active mesh (use_grid)"
     ms = (m.shape[pmesh.ROW_AXIS], m.shape[pmesh.COL_AXIS])
@@ -687,11 +728,13 @@ def getrf_cyclic(A: CyclicMatrix):
     _ring_span(A, m, ring, rch)
     out, wins, active = _getrf_cyclic_jit(A.data, A.desc, m,
                                           _cyclic_lookahead(), pk,
-                                          ring, rch)
+                                          ring, rch, pivot, update_dtype)
     desc = A.desc
     d = desc.dist
     mb = desc.mb
     Mp = desc.MT * mb
+    if not pivot:
+        return CyclicMatrix(out, desc), jnp.arange(Mp, dtype=wins.dtype)
     KT = min(desc.MT, desc.NT)
     win_flat = wins[0, 0].reshape(-1)
     nleft = Mp - KT * mb  # static: winners cover exactly KT*mb rows

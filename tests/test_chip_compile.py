@@ -72,6 +72,35 @@ def test_matmul_compiles(one_chip):
     _mosaic(lambda a, b: pk.matmul(a, b, interpret=False), x, x)
 
 
+@pytest.mark.parametrize("n", [128, 512])
+def test_lu_nopiv_tile_compiles(one_chip, n):
+    """The diagonal-block LU of the grid sweep without pivoting, up to
+    its largest tile (a 1024 tile overflows scoped VMEM)."""
+    from dplasma_tpu.kernels import pallas_lu
+    assert n <= pallas_lu.NOPIV_MAX
+    _mosaic(lambda a: pallas_lu._nopiv_call(a, False),
+            _sds((n, n), jnp.float32, one_chip))
+
+
+def test_a2a_conversion_memory_on_chip(topo):
+    """The all_to_all conversion to cyclic slabs holds about two local
+    blocks (the received pieces and the result) on a v5e, not one piece
+    stack per destination slot: with 32 row tiles a stack of whole
+    pieces took 8 local blocks, and at HPL-MxP's N 32768 (64 tiles)
+    16 GiB of a chip's 15.75."""
+    from dplasma_tpu.descriptors import Dist, TileDesc, TileMatrix
+    from dplasma_tpu.parallel import cyclic
+    mesh = Mesh(np.asarray(topo.devices[:4]).reshape(2, 2), ("p", "q"))
+    n, nb = 4096, 128
+    desc = TileDesc(n, n, nb, nb)
+    f = jax.jit(lambda a: cyclic.from_tile_a2a(
+        TileMatrix(a, desc), Dist(P=2, Q=2), mesh).data)
+    stats = f.lower(_sds((n, n), jnp.float32, NamedSharding(
+        mesh, P("p", "q")))).compile().memory_analysis()
+    local = n * n * 4 // 4
+    assert stats.temp_size_in_bytes <= 3 * local, stats.temp_size_in_bytes
+
+
 # The fused panels (opt-in: MCA panel.kernel=pallas) have no Mosaic
 # lowering yet: the LU kernel slices a zero-width block and both write
 # their pivot/tau vectors by scatter. Strict, so a fix must say so.

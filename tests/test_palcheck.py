@@ -36,13 +36,14 @@ def test_package_pallas_sites_verify_clean():
     contract passes every check."""
     res = pc.check_package()
     assert res.ok, res.format()
-    assert res.sites_found == 6    # pallas_kernels, _lu, _qr, _dd,
+    assert res.sites_found == 7    # pallas_kernels, _lu (panel +
+    #                              # nopiv tile), _qr, _dd,
     #                              # _ring (bcast + shift)
     if res.skipped is None:
-        assert res.contracts == 7        # gemm epilogue + matmul +
-        #                                # lu panel + qr panel +
-        #                                # dd recombine + ring bcast
-        #                                # + ring shift
+        assert res.contracts == 8        # gemm epilogue + matmul +
+        #                                # lu panel + nopiv tile +
+        #                                # qr panel + dd recombine +
+        #                                # ring bcast + ring shift
 
 
 def test_every_site_is_registered():
